@@ -311,6 +311,9 @@ def main() -> None:
     ap.add_argument("--json", default=None,
                     help="write the summary as JSON to this path")
     args = ap.parse_args(sys.argv[1:])
+    from benchmarks import common  # noqa: F401  (puts src/ on sys.path)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         sys.exit(smoke(args.json))
 

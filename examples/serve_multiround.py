@@ -16,10 +16,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import get_config
 from repro.core.types import SLOSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import ClusterSpec, LiveCluster, make_live_sessions
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("qwen2.5-14b").reduced()   # same family, CPU-sized
     print(f"model: {cfg.name} (reduced: {cfg.num_layers}L d={cfg.d_model})")
 

@@ -143,27 +143,6 @@ def named_sharding(mesh: Mesh, *parts) -> NamedSharding:
     return NamedSharding(mesh, P(*parts))
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs,
-              axis_names: Optional[frozenset] = None,
-              check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=, check_vma=)``; older
-    releases have ``jax.experimental.shard_map.shard_map(..., auto=,
-    check_rep=)`` where ``auto`` is the complement of the manual axis set.
-    Model code always passes the *manual* axes (``axis_names``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    manual = frozenset(axis_names if axis_names is not None
-                       else mesh.axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      auto=frozenset(mesh.axis_names) - manual,
-                      check_rep=check_vma)
-
-
 # ---------------------------------------------------------------------------
 # Rule presets
 # ---------------------------------------------------------------------------

@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes TPU compiler options as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 INVALID_POS = -(2 ** 30)
 DEFAULT_BLOCK_KV = 512
@@ -52,8 +48,8 @@ def _decode_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = qpos_ref[0][:, None]                                # (rows, 1)
-    kp = kpos_ref[0][None, :]                                # (1, bkv)
+    qp = qpos_ref[0]                                         # (rows, 1)
+    kp = kpos_ref[0]                                         # (1, bkv)
     mask = (kp > (INVALID_POS // 2)) & (kp <= qp)
     if window is not None:
         mask &= (qp - kp) < window
@@ -99,12 +95,14 @@ def decode_attn_bgrd(
 
     kernel = functools.partial(_decode_kernel, scale=scale, softcap=softcap,
                                window=window, nk=nk)
+    # positions as (B, rows, 1) / (B, 1, T) so every block's last two dims
+    # are sublane/lane aligned or whole, as Mosaic requires at any B
     out, m, l = pl.pallas_call(
         kernel,
         grid=(B, G, nk),
         in_specs=[
-            pl.BlockSpec((1, rows), lambda b, g, ki: (b, 0)),
-            pl.BlockSpec((1, block_kv), lambda b, g, ki: (b, ki)),
+            pl.BlockSpec((1, rows, 1), lambda b, g, ki: (b, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, g, ki: (b, 0, ki)),
             pl.BlockSpec((1, 1, rows, hd), lambda b, g, ki: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, g, ki: (b, g, ki, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b, g, ki: (b, g, ki, 0)),
@@ -124,10 +122,10 @@ def decode_attn_bgrd(
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_positions, kv_positions, q, k, v)
+    )(q_positions[:, :, None], kv_positions[:, None, :], q, k, v)
     return out, m[..., 0], l[..., 0]
 
 

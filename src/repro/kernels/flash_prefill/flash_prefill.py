@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes TPU compiler options as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 INVALID_POS = -(2 ** 30)
 DEFAULT_BLOCK_Q = 128
@@ -60,8 +56,8 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,   # inputs
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = qpos_ref[0][:, None]                                # (bq, 1)
-    kp = kpos_ref[0][None, :]                                # (1, bkv)
+    qp = qpos_ref[0]                                         # (bq, 1)
+    kp = kpos_ref[0]                                         # (1, bkv)
     mask = kp > (INVALID_POS // 2)
     if causal:
         mask &= kp <= qp
@@ -113,13 +109,15 @@ def flash_prefill_bhsd(
         _flash_kernel, scale=scale, softcap=softcap, window=window,
         causal=causal, nk=nk)
 
+    # positions as (B, S, 1) / (B, 1, T): every block's last two dims are
+    # then sublane/lane aligned or whole, as Mosaic requires at any B
     grid = (B, H, nq, nk)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)),
-            pl.BlockSpec((1, block_kv), lambda b, h, qi, ki: (b, ki)),
+            pl.BlockSpec((1, block_q, 1), lambda b, h, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, h, qi, ki: (b, 0, ki)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_kv, hd),
                          lambda b, h, qi, ki, _qpg=qpg: (b, h // _qpg, ki, 0)),
@@ -134,8 +132,8 @@ def flash_prefill_bhsd(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_positions, kv_positions, q, k, v)
+    )(q_positions[:, :, None], kv_positions[:, None, :], q, k, v)
     return out

@@ -32,10 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 exposes TPU compiler options as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 INVALID_POS = -(2 ** 30)
 DEFAULT_BLOCK_Q = 128
@@ -66,8 +62,8 @@ def _ragged_kernel(rows_ref,                                  # scalar prefetch
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = qpos_ref[0][:, None]                                # (bq, 1)
-    kp = kpos_ref[0][None, :]                                # (1, bkv)
+    qp = qpos_ref[...]                                       # (bq, 1)
+    kp = kpos_ref[0]                                         # (1, bkv)
     mask = (kp > (INVALID_POS // 2)) & (qp > (INVALID_POS // 2))
     if causal:
         mask &= kp <= qp
@@ -121,14 +117,16 @@ def ragged_fused_hpd(
         _ragged_kernel, scale=scale, softcap=softcap, window=window,
         causal=causal, nk=nk)
 
+    # positions as (P, 1) / (B, 1, T) so every block's last two dims are
+    # sublane/lane aligned or whole, as Mosaic requires at block_q = 8
     grid = (H, nq, nk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda h, qi, ki, rows: (0, qi)),
-            pl.BlockSpec((1, block_kv),
-                         lambda h, qi, ki, rows: (rows[qi], ki)),
+            pl.BlockSpec((block_q, 1), lambda h, qi, ki, rows: (qi, 0)),
+            pl.BlockSpec((1, 1, block_kv),
+                         lambda h, qi, ki, rows: (rows[qi], 0, ki)),
             pl.BlockSpec((1, block_q, hd), lambda h, qi, ki, rows: (h, qi, 0)),
             pl.BlockSpec((1, 1, block_kv, hd),
                          lambda h, qi, ki, rows, _qpg=qpg:
@@ -149,8 +147,9 @@ def ragged_fused_hpd(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, P, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_rows, q_positions, kv_positions, q, k, v)
+    )(block_rows, q_positions.reshape(P, 1), kv_positions[:, None, :],
+      q, k, v)
     return out

@@ -10,20 +10,11 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-import jax.sharding
-from jax.sharding import Mesh
-
-# jax < 0.5 has neither jax.sharding.AxisType nor make_mesh(axis_types=...);
-# explicit Auto axes only matter under shard_map-style manual collectives,
-# so older versions simply take the default typing.
-AxisType = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -37,15 +28,10 @@ def make_worker_mesh(tp: int, dp: int = 1) -> Mesh:
     return _make_mesh((dp, tp), ("data", "model"))
 
 
-def make_abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-free mesh for sharding-rule evaluation, across jax versions:
-    new jax takes AbstractMesh(shape, names, axis_types=...); 0.4.x takes a
-    single ((name, size), ...) tuple."""
-    from jax.sharding import AbstractMesh
-    if AxisType is not None:
-        return AbstractMesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
-    return AbstractMesh(tuple(zip(axes, shape)))
+def make_abstract_mesh(shape: Tuple[int, ...],
+                       axes: Tuple[str, ...]) -> AbstractMesh:
+    """Device-free mesh for sharding-rule evaluation."""
+    return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> Mesh:

@@ -23,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard, shard_map
+from repro.distributed.sharding import shard
 from repro.models.common import softcap as _softcap
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -251,7 +251,7 @@ def context_parallel_decode(
         out = jax.lax.psum(part, model_axis)                   # (B, d)
         return out[:, None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=env.mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, model_axis, None, None),
                   P(bspec, model_axis, None, None), P(bspec, None),

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import current_env, shard_map
+from repro.distributed.sharding import current_env
 from repro.models.common import activate, spec
 
 
@@ -203,9 +203,9 @@ def moe_apply(cfg, p: Dict[str, jax.Array], x: jax.Array,
             dropped = jax.lax.psum(dropped, batch_axes)
             return out.reshape(Bl, Sl, d), aux, dropped
 
-        fn = shard_map(body, mesh=env.mesh, in_specs=(bspec, wspec),
-                       out_specs=(bspec, P(), P()),
-                       axis_names=frozenset(manual), check_vma=False)
+        fn = jax.shard_map(body, mesh=env.mesh, in_specs=(bspec, wspec),
+                           out_specs=(bspec, P(), P()),
+                           axis_names=frozenset(manual), check_vma=False)
         out, aux, dropped = fn(x, p)
         return out, {"moe_aux_loss": aux, "moe_dropped": dropped}
 
@@ -223,8 +223,8 @@ def moe_apply(cfg, p: Dict[str, jax.Array], x: jax.Array,
         group_sizes = jax.lax.psum(group_sizes, batch_axes)
         return out.reshape(Bl, Sl, d), aux, group_sizes
 
-    fn = shard_map(body, mesh=env.mesh, in_specs=(bspec, wspec),
-                   out_specs=(bspec, P(), P(None)),
-                   axis_names=frozenset(manual), check_vma=False)
+    fn = jax.shard_map(body, mesh=env.mesh, in_specs=(bspec, wspec),
+                       out_specs=(bspec, P(), P(None)),
+                       axis_names=frozenset(manual), check_vma=False)
     out, aux, group_sizes = fn(x, p)
     return out, {"moe_aux_loss": aux, "moe_group_sizes": group_sizes}

@@ -11,9 +11,12 @@ predicted: the CPU-scale twin of a TPU deployment.
 Three transports (DESIGN.md §13/§16) behind one contract:
 
   * ``transport="inproc"`` (default): workers execute logically in parallel
-    inside this process — cheap, CI-friendly, KV moves as device copies.
+    inside this process, placed one per local device in turn (``devices``)
+    — KV moves as device copies, chip to chip on a multi-chip host.  This
+    is the transport of a chip host: a chip belongs to one process.
   * ``transport="proc"``: every worker is a real OS process owning its own
-    JAX engine; KV bytes move over AF_UNIX RPC sockets
+    JAX engine (CPU and multi-host setups; refused on a TPU parent, which
+    already holds the chip); KV bytes move over AF_UNIX RPC sockets
     (:class:`~repro.serving.kv_transfer.TransportKVPath` measures them) and
     ``fail_worker`` delivers a real ``SIGKILL``.
   * ``transport="tcp"``: the same worker processes over TCP stream sockets,
@@ -35,8 +38,9 @@ import dataclasses
 import time
 import warnings
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -178,7 +182,8 @@ class LiveCluster:
                  transport=None, policy: Optional[SchedPolicy] = None,
                  slo: Optional[SLOSpec] = None, seed: int = 0,
                  model_kv_time: bool = False, profile: bool = True,
-                 lattice=None, **legacy):
+                 lattice=None, devices: Optional[Sequence] = None,
+                 **legacy):
         spec, tcfg, policy = _shim_legacy_kwargs(spec, transport, policy,
                                                  legacy)
         entry = TRANSPORT_REGISTRY[tcfg.kind]
@@ -196,6 +201,10 @@ class LiveCluster:
 
         self.prefill_workers: List = []
         self.decode_workers: List = []
+        #: devices in-process tp=1 workers are placed on, one worker per
+        #: device in turn (prefill workers first); tp>1 workers own a mesh
+        self.devices = (list(devices or jax.local_devices())
+                        if spec.tp == 1 else [None])
         if entry.multiprocess:
             from repro.serving.kv_transfer import TransportKVPath
             from repro.serving.worker_proc import ProcWorkerPool
@@ -216,11 +225,12 @@ class LiveCluster:
                 if i < len(policy.prefill_classes):
                     w.pclass = policy.prefill_classes[i]   # dedicated (§19)
         else:
-            key = __import__("jax").random.PRNGKey(seed)
+            key = jax.random.PRNGKey(seed)
             shared_engine_params = None
             for i in range(spec.n_prefill):
                 eng = Engine(cfg, max_len=spec.max_len, key=key,
-                             params=shared_engine_params, tp=spec.tp)
+                             params=shared_engine_params, tp=spec.tp,
+                             device=self._device_for("prefill", i))
                 shared_engine_params = eng.params
                 w = LivePrefillWorker(i, eng, tp=spec.tp)
                 if i < len(policy.prefill_classes):
@@ -228,7 +238,8 @@ class LiveCluster:
                 self.prefill_workers.append(w)
             for i in range(spec.n_decode):
                 eng = Engine(cfg, max_len=spec.max_len, key=key,
-                             params=shared_engine_params, tp=spec.tp)
+                             params=shared_engine_params, tp=spec.tp,
+                             device=self._device_for("decode", i))
                 shared_engine_params = eng.params
                 # planner-chosen per-worker chunk size (Deployment.decode_chunks())
                 per_worker = (policy.decode_chunk_tokens[i]
@@ -336,11 +347,17 @@ class LiveCluster:
         return LinkTopology(hosts=dict(self._pool.worker_hosts),
                             colocated=False, default_host=self._pool.host)
 
+    def _device_for(self, kind: str, idx: int):
+        """Device of the in-process worker with stable id ``idx``: prefill
+        workers take devices 0.., decode workers follow them, wrapping."""
+        k = idx if kind == "prefill" else self.spec.n_prefill + idx
+        return self.devices[k % len(self.devices)]
+
     def _probe_engine(self) -> Engine:
         if self._pool is None:
             return (self.prefill_workers[0].engine if self.prefill_workers
                     else self.decode_workers[0].engine)
-        key = __import__("jax").random.PRNGKey(self._seed)
+        key = jax.random.PRNGKey(self._seed)
         return Engine(self.cfg, max_len=self._max_len, key=key,
                       tp=self.spec.tp)
 
@@ -384,7 +401,8 @@ class LiveCluster:
             ref = (self.prefill_workers[0] if self.prefill_workers
                    else self.decode_workers[0])
             eng = Engine(self.cfg, max_len=ref.engine.max_len,
-                         params=ref.engine.params, tp=self.spec.tp)
+                         params=ref.engine.params, tp=self.spec.tp,
+                         device=self._device_for("prefill", next_id))
             w = LivePrefillWorker(next_id, eng, tp=self.spec.tp)
         # keep the priced topology in step with the elastic scale-out —
         # on BOTH branches (the inproc topology is degenerate today, but a
@@ -404,7 +422,8 @@ class LiveCluster:
             ref = (self.decode_workers[0] if self.decode_workers
                    else self.prefill_workers[0])
             eng = Engine(self.cfg, max_len=ref.engine.max_len,
-                         params=ref.engine.params, tp=self.spec.tp)
+                         params=ref.engine.params, tp=self.spec.tp,
+                         device=self._device_for("decode", next_id))
             w = LiveDecodeWorker(next_id, eng, max_slots=self.spec.max_slots,
                                  tp=self.spec.tp, chunk_tokens=chunk_tokens,
                                  packed=self.policy.packed)
@@ -510,8 +529,12 @@ def make_live_sessions(cfg: ModelConfig, *, num_sessions: int = 4,
                        seed: int = 0,
                        shared_prefix: int = 0,
                        tenants: Optional[List[str]] = None,
+                       incr_len: Optional[int] = None,
                        ) -> List[LiveSession]:
     """Synthetic multi-round sessions over real token ids.
+
+    Round 0 prompts ``prefill_len`` tokens; every later round appends an
+    increment of ``incr_len`` tokens (default ``prefill_len``).
 
     ``shared_prefix``: the first N tokens of every round-0 prompt are drawn
     ONCE and shared verbatim across sessions (a common system prompt /
@@ -527,12 +550,14 @@ def make_live_sessions(cfg: ModelConfig, *, num_sessions: int = 4,
     shared = (rng.integers(0, cfg.vocab_size,
                            min(shared_prefix, prefill_len)).astype(np.int32)
               if shared_prefix > 0 else None)
+    lens = [prefill_len if r == 0 or incr_len is None else incr_len
+            for r in range(rounds)]
     out = []
     for sid in range(num_sessions):
-        rs = [RoundSpec(prefill_len=prefill_len, decode_len=decode_len,
-                        env_delay=0.0) for _ in range(rounds)]
-        prompts = [rng.integers(0, cfg.vocab_size, prefill_len).astype(np.int32)
-                   for _ in range(rounds)]
+        rs = [RoundSpec(prefill_len=n, decode_len=decode_len, env_delay=0.0)
+              for n in lens]
+        prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+                   for n in lens]
         if shared is not None:
             prompts[0] = np.concatenate(
                 [shared, prompts[0][len(shared):]]).astype(np.int32)

@@ -16,6 +16,10 @@ devices the engine falls back to an unsharded 1x1 layout (the declared
 ``tp`` is still what the scheduler prices) — worker child processes get
 their device count forced by the pool so the fallback never triggers there.
 
+Placement: a tp=1 engine can be given one ``device``; its params and
+every cache it creates then live on that device only, and its jitted steps
+run there (the cluster places in-process workers one per local device).
+
 ``profile_engine`` measures the engine across a small grid of shapes and
 fits the AMPD perf-model coefficients (§3 offline profiler): the scheduler
 is then driven by *measured* numbers, not analytic constants.  With
@@ -62,7 +66,8 @@ def chunk_limit(cfg: ModelConfig, max_len: int) -> int:
 class Engine:
     def __init__(self, model_or_cfg, *, max_len: int, key: Optional[jax.Array] = None,
                  params: Optional[Any] = None, opts: Optional[StepOptions] = None,
-                 impl: str = "auto", tp: int = 1):
+                 impl: str = "auto", tp: int = 1,
+                 device: Optional[jax.Device] = None):
         self.model: Model = (model_or_cfg if isinstance(model_or_cfg, Model)
                              else build_model(model_or_cfg))
         self.cfg = self.model.cfg
@@ -93,10 +98,13 @@ class Engine:
                     f"device(s) visible; engine runs unsharded (scheduler "
                     f"still prices tp={tp})", RuntimeWarning, stacklevel=2)
 
-        self.params = params if params is not None else self.model.init(
-            key if key is not None else jax.random.PRNGKey(0))
-        if self.sharding_env is not None:
-            self.params = jax.device_put(self.params, self._replicated())
+        #: the device holding this engine's params and caches (None: JAX's
+        #: default device, or the tp mesh when sharded)
+        self.device = device if self.sharding_env is None else None
+        with jax.default_device(self.device):
+            self.params = self._place(
+                params if params is not None else self.model.init(
+                    key if key is not None else jax.random.PRNGKey(0)))
 
         cfg = self.cfg
         o = self.opts
@@ -133,11 +141,18 @@ class Engine:
         from repro.distributed.sharding import axis_rules
         return axis_rules(self.sharding_env)
 
-    def new_cache(self, batch: int):
-        cache = init_cache(self.cfg, batch, self.max_len)
+    def _place(self, tree):
+        """Commit a tree to this engine's placement: replicated on its tp
+        mesh, or on its one device (created there, so nothing moves)."""
         if self.sharding_env is not None:
-            cache = jax.device_put(cache, self._replicated())
-        return cache
+            return jax.device_put(tree, self._replicated())
+        if self.device is not None:
+            return jax.device_put(tree, self.device)
+        return tree
+
+    def new_cache(self, batch: int):
+        with jax.default_device(self.device):
+            return self._place(init_cache(self.cfg, batch, self.max_len))
 
     def pad_chunk(self, tokens: np.ndarray, batch: int = 1) -> jnp.ndarray:
         """Right-pad a token chunk to the engine's padding multiple."""
@@ -415,9 +430,9 @@ def profile_engine(engine: Engine, perf: PerfModel, tp: int,
             kv_samples = []
             for l in lens:
                 def call(lo=0, hi=l):
-                    ext = extract_range(src, cfg, engine.max_len, lo, hi)
-                    return insert_range(dst, reshard(ext), cfg,
-                                        engine.max_len, lo, 0,
+                    ext = reshard(extract_range(src, cfg, engine.max_len,
+                                                lo, hi), engine.device)
+                    return insert_range(dst, ext, cfg, engine.max_len, lo, 0,
                                         replace_state=True)
 
                 dt, _ = _time_call(call)

@@ -1,9 +1,10 @@
 """Session-state (KV / SSM / LRU) transfer between workers.
 
 The TPU adaptation of NIXL point-to-point RDMA (paper §6): cache slices move
-between worker mesh slices as explicit array reshards (``jax.device_put`` to
-the destination sharding — on one CPU device this degenerates to copies, but
-the byte accounting and the lazy-read/incremental-write protocol are real):
+between workers as explicit ``jax.device_put`` onto the destination
+engine's device (``reshard``) — chip to chip when in-process workers sit on
+different devices, a no-op on one device, where the byte accounting and the
+lazy-read/incremental-write protocol stay real:
 
   * ``extract_range``    pull a [lo, hi) token range of one batch row —
     seq-dim slices for full-attention K/V + positions, whole-state copies
@@ -275,14 +276,11 @@ def _numpy_tree(tree: Cache) -> Cache:
     return jax.tree.map(lambda x: np.asarray(x), tree)
 
 
-def reshard(tree: Cache, target_shardings=None) -> Cache:
-    """Move a cache tree to another worker's device layout.
+def reshard(tree: Cache, device: Optional[jax.Device] = None) -> Cache:
+    """Move a cache tree onto the destination engine's device.
 
-    With real multi-host meshes this is the ICI point-to-point transfer; on
-    the single-device CPU runtime it is a device_put to the same device (the
-    protocol and byte accounting stay identical).
+    Between in-process workers placed on different chips this is the
+    chip-to-chip copy of the KV handoff; on one device it moves nothing.
+    ``device=None`` (an unplaced engine) means JAX's default device.
     """
-    if target_shardings is None:
-        return jax.device_put(tree)
-    return jax.tree.map(lambda x, s: jax.device_put(x, s), tree,
-                        target_shardings)
+    return jax.device_put(tree, device)

@@ -65,7 +65,11 @@ from repro.configs.base import ModelConfig
 from repro.core.types import PrefillTask
 from repro.runtime.backend import WorkerDiedError
 from repro.serving import rpc
-from repro.serving.workers import SlotBookkeeping, WorkerSchedState
+from repro.serving.workers import (
+    SlotBookkeeping,
+    WorkerSchedState,
+    resolve_packed,
+)
 from repro.serving.kv_transfer import (
     TransportKVPath,
     _numpy_tree,
@@ -199,8 +203,8 @@ def _decode_handlers(worker):                        # pragma: no cover — runs
         return {"eng_s": dt, "first": first, "toks": toks}
 
     def kv_put(slot, lo, tree):
-        worker.cache = insert_range(worker.cache, reshard(tree), eng.cfg,
-                                    eng.max_len, int(lo), int(slot),
+        worker.cache = insert_range(worker.cache, reshard(tree, eng.device),
+                                    eng.cfg, eng.max_len, int(lo), int(slot),
                                     replace_state=True)
         jax.block_until_ready(jax.tree.leaves(worker.cache)[0])
         return None
@@ -468,6 +472,22 @@ def _src_root() -> str:
     return os.path.dirname(os.path.dirname(here))       # .../src
 
 
+def _child_env(tp: int) -> Dict[str, str]:
+    """Environment of a worker child: ``repro`` importable, and JAX pinned
+    to the parent's platform (a child never drops to another backend)."""
+    import jax
+    env = os.environ.copy()
+    env["PYTHONPATH"] = _src_root() + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = jax.default_backend()
+    if tp > 1:
+        # a tp-way mesh needs tp devices; on the CPU platform force the
+        # host device count BEFORE the child imports jax (the same trick
+        # the dry-run entrypoint uses for production-scale meshes)
+        flag = f"--xla_force_host_platform_device_count={tp}"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
+    return env
+
+
 class ProcWorkerPool:
     """Owns the coordinator socket and every spawned worker process.
 
@@ -494,6 +514,14 @@ class ProcWorkerPool:
         if not entry.multiprocess:
             raise ValueError(
                 f"transport {tcfg.kind!r} does not spawn worker processes")
+        import jax
+        if jax.default_backend() == "tpu":
+            # a chip belongs to one process, and this one already holds it
+            raise RuntimeError(
+                f"the {tcfg.kind!r} transport starts one JAX process per "
+                "worker, but this process already holds the TPU; on a chip "
+                "host serve with transport='inproc'")
+        resolve_packed(cfg, packed)     # refuse here, not in every child
         self.cfg = cfg
         self.max_len = max_len
         self.max_slots = max_slots
@@ -525,18 +553,7 @@ class ProcWorkerPool:
 
     # -- spawning ------------------------------------------------------------
     def _launch(self, kind: str, idx: int) -> subprocess.Popen:
-        env = os.environ.copy()
-        env["PYTHONPATH"] = _src_root() + os.pathsep + env.get("PYTHONPATH", "")
-        # default children to CPU so they don't fight the coordinator for a
-        # device; an operator who pins JAX_PLATFORMS explicitly (e.g. to
-        # hand each worker its own accelerator) keeps their setting
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        if self.tp > 1:
-            # a tp-way mesh needs tp devices; on the CPU platform force the
-            # host device count BEFORE the child imports jax (the same trick
-            # the dry-run entrypoint uses for production-scale meshes)
-            flag = f"--xla_force_host_platform_device_count={self.tp}"
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
+        env = _child_env(self.tp)
         log = open(os.path.join(self._dir, f"{kind}{idx}.log"), "wb")
         cmd = [sys.executable, "-m", "repro.serving.worker_proc",
                "--socket", self.dial_spec, "--kind", kind,
@@ -606,14 +623,12 @@ class ProcWorkerPool:
                 w = ProcPrefillWorker(idx, client, proc, self.cfg,
                                       self.max_len, self.kv_path, tp=self.tp)
             else:
-                from repro.models.packed import supports_packed
-                resolved = (self.packed is not False
-                            and supports_packed(self.cfg))
                 w = ProcDecodeWorker(idx, client, proc, self.cfg,
                                      self.max_len, self.kv_path,
                                      max_slots=self.max_slots, tp=self.tp,
                                      chunk_tokens=chunks[(kind, idx)],
-                                     packed=resolved)
+                                     packed=resolve_packed(self.cfg,
+                                                           self.packed))
             w.host = worker_host
             w.link_class = link
             out[(kind, idx)] = w
@@ -631,7 +646,8 @@ class ProcWorkerPool:
 
     # -- teardown ------------------------------------------------------------
     def close(self) -> None:
-        if self._closed:
+        # a pool refused in __init__ never opened anything
+        if getattr(self, "_closed", True):
             return
         self._closed = True
         for w in self.workers:

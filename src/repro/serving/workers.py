@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.types import PrefillTask, RoundSpec
+from repro.models.packed import supports_packed
 from repro.runtime.metrics import WindowStat
 from repro.serving.engine import Engine, chunk_limit
 from repro.serving.kv_transfer import (
@@ -69,6 +70,18 @@ def chunk_tokens_of(task: PrefillTask, session: LiveSession) -> np.ndarray:
     if task.incr_offset == 0 and task.l_incr >= len(toks):
         return toks
     return toks[task.incr_offset:task.incr_offset + task.l_incr]
+
+
+def resolve_packed(cfg: ModelConfig, packed: Optional[bool]) -> bool:
+    """Whether a decode worker runs the ragged packed fused path: ``None``
+    follows the architecture; ``True`` on an arch without a ragged pack is
+    refused rather than served dense."""
+    if packed is None:
+        return supports_packed(cfg)
+    if packed and not supports_packed(cfg):
+        raise ValueError(f"packed=True, but {cfg.name} has no ragged pack "
+                         f"(layer pattern {cfg.layer_pattern})")
+    return bool(packed)
 
 
 def timed(fn, *args, **kw):
@@ -162,8 +175,9 @@ class LivePrefillWorker(WorkerSchedState):
         tokens = chunk_tokens_of(task, session)
         if history_extract is not None and task.l_hist > 0:
             cache = eng.new_cache(1)
-            cache = insert_range(cache, reshard(history_extract), eng.cfg,
-                                 eng.max_len, 0, 0, replace_state=True)
+            cache = insert_range(cache, reshard(history_extract, eng.device),
+                                 eng.cfg, eng.max_len, 0, 0,
+                                 replace_state=True)
             self.kv_bytes_moved += transfer_bytes(history_extract)
             lim = chunk_limit(eng.cfg, eng.max_len)
             logits = None
@@ -194,10 +208,8 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         self.slots: List[Optional[LiveSession]] = [None] * max_slots
         self.mem_tokens = 0
         #: ragged packed fused path (DESIGN.md §15): None = auto (on when the
-        #: arch has a ragged pack); explicitly requesting packed on an
-        #: unsupported arch silently falls back to dense.
-        self.packed = (engine.supports_packed if packed is None
-                       else bool(packed) and engine.supports_packed)
+        #: arch has a ragged pack)
+        self.packed = resolve_packed(engine.cfg, packed)
         #: fused-step telemetry for LiveResult / fig14
         self.fused_steps = 0
         self.fused_s = 0.0
@@ -218,7 +230,8 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         uniform across local and remote placement."""
         if session.slot is None:
             self.allocate(session)
-        self.cache = insert_range(self.cache, reshard(increment),
+        self.cache = insert_range(self.cache,
+                                  reshard(increment, self.engine.device),
                                   self.engine.cfg, self.engine.max_len,
                                   lo, session.slot, replace_state=True)
         session.last_token = first_token

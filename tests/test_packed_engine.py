@@ -85,9 +85,12 @@ def test_packed_unsupported_arch_gated():
     assert not eng.supports_packed
     with pytest.raises(AssertionError):
         eng.run_packed(eng.new_cache(1), [(0, np.zeros(4, np.int32))])
-    # the worker silently falls back to dense even when packed is requested
-    w = LiveDecodeWorker(0, eng, max_slots=2, packed=True)
-    assert not w.packed
+    # asking for the packed path on an arch without a ragged pack is refused
+    # (never served dense in silence); auto and explicit-off go dense
+    with pytest.raises(ValueError, match="no ragged pack"):
+        LiveDecodeWorker(0, eng, max_slots=2, packed=True)
+    assert not LiveDecodeWorker(0, eng, max_slots=2).packed
+    assert not LiveDecodeWorker(0, eng, max_slots=2, packed=False).packed
 
 
 # ---------------------------------------------------------------------------
