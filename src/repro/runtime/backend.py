@@ -20,6 +20,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.perf_model import PerfModel
 from repro.core.types import PrefillTask
+from repro.runtime.tracing import span
 
 #: payload of a completed prefill: (placement, kv_increment, first_token)
 PrefillPayload = Tuple[str, Optional[Dict], Optional[int]]
@@ -354,55 +355,56 @@ class LiveBackend(ExecutionBackend):
         return decode_worker.history_extract(session)
 
     def run_prefill(self, worker, task, session, decode_worker):
-        import numpy as np
-        from repro.serving.workers import timed
         if worker.kind == "prefill":
-            hist = None
-            if task.l_hist > 0 and session.slot is not None:
-                hist = self._read_history(worker, task, session,
-                                          decode_worker)
-            dt, out = timed(worker.execute, task, session,
-                            history_extract=hist)
-            dt /= worker.speed
-            if self.model_kv_time:
-                dt += (self.perf.t_kv_between(task.l_hist, decode_worker,
-                                              worker)
-                       + self.perf.t_kv_between(task.l_incr, worker,
-                                                decode_worker))
-            payload = ("remote", out["increment"],
-                       int(np.argmax(out["logits"])))
-            if self.kv_store is not None:
-                # the chunk's history + increment are in hand right here:
-                # stage them so completion-time page capture can slice any
-                # span of [0, l_hist + l_incr)
-                parts = []
-                if hist is not None:
-                    parts.append((0, task.l_hist, hist))
-                parts.append((task.l_hist, task.l_hist + task.l_incr,
-                              out["increment"]))
-                self.kv_store.stage(("prefill", worker.idx), parts)
-        else:
-            dt, first = worker.local_prefill(task, session)
-            dt /= worker.speed
-            payload = ("local", None, first)
-        return dt, payload
+            with span("repro.prefill", session=task.session_id,
+                      round=task.round_idx):
+                return self._run_remote(worker, task, session, decode_worker)
+        dt, first = worker.local_prefill(task, session)
+        return dt / worker.speed, ("local", None, first)
+
+    def _run_remote(self, worker, task, session, decode_worker):
+        import numpy as np
+        from repro.serving.workers import kv_op, timed
+        hist = None
+        if task.l_hist > 0 and session.slot is not None:
+            hist = kv_op(worker, "repro.kv.read_history", self._read_history,
+                         worker, task, session, decode_worker)
+        dt, out = timed(worker.execute, task, session, history_extract=hist)
+        dt /= worker.speed
+        if self.model_kv_time:
+            dt += (self.perf.t_kv_between(task.l_hist, decode_worker, worker)
+                   + self.perf.t_kv_between(task.l_incr, worker,
+                                            decode_worker))
+        if self.kv_store is not None:
+            # the chunk's history + increment are in hand right here: stage
+            # them so completion-time page capture can slice any span of
+            # [0, l_hist + l_incr)
+            parts = []
+            if hist is not None:
+                parts.append((0, task.l_hist, hist))
+            parts.append((task.l_hist, task.l_hist + task.l_incr,
+                          out["increment"]))
+            self.kv_store.stage(("prefill", worker.idx), parts)
+        return dt, ("remote", out["increment"], int(np.argmax(out["logits"])))
 
     def on_join(self, decode_worker, session, task, payload) -> None:
         placement, increment, first = payload
-        if placement == "remote":
-            decode_worker.attach(session, increment, task.l_hist, first,
-                                 task.l_incr)
-            if self.kv_store is not None:
-                # the increment tree is in hand at the join: stage it so
-                # the decode-side page capture can slice [l_hist, +l_incr)
-                self.kv_store.stage(
-                    ("decode", decode_worker.idx),
-                    [(task.l_hist, task.l_hist + task.l_incr, increment)])
-        else:
-            session.last_token = first
-        toks = session.prompt_tokens[task.round_idx][
-            task.incr_offset:task.incr_offset + task.l_incr]
-        session.transcript.extend(int(t) for t in toks)
+        with span("repro.join", session=task.session_id,
+                  round=task.round_idx):
+            if placement == "remote":
+                decode_worker.attach(session, increment, task.l_hist, first,
+                                     task.l_incr)
+                if self.kv_store is not None:
+                    # the increment tree is in hand at the join: stage it so
+                    # the decode-side page capture can slice [l_hist, +l_incr)
+                    self.kv_store.stage(
+                        ("decode", decode_worker.idx),
+                        [(task.l_hist, task.l_hist + task.l_incr, increment)])
+            else:
+                session.last_token = first
+            toks = session.prompt_tokens[task.round_idx][
+                task.incr_offset:task.incr_offset + task.l_incr]
+            session.transcript.extend(int(t) for t in toks)
 
     def attached(self, decode_worker) -> List:
         return [s for s in decode_worker.slots if s is not None]

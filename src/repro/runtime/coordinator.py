@@ -353,7 +353,6 @@ class Coordinator:
             t_self += self.perf.t_pre(mine.l_hist, mine.l_incr, thief.tp,
                                       thief.speed)
         best: Optional[Tuple[float, object, PrefillTask]] = None
-        examined = False
         for v in prefill_workers:
             if v is thief or not v.alive or not v.prefill_queue:
                 continue
@@ -367,7 +366,6 @@ class Coordinator:
                     continue                    # superseded by a rebind
                 if not class_eligible(thief, k):
                     continue                    # class-dedicated pool (§19)
-                examined = True
                 move_read = 0.0
                 if (k.l_hist > 0 and getattr(s, "_rt_chain_worker", None)
                         != ("prefill", thief.idx)):
@@ -385,12 +383,9 @@ class Coordinator:
                         best is None or profit > best[0]):
                     best = (profit, v, k)
         if best is None:
-            if examined:
-                self.sched.steal_rejected += 1
             return None
         _, victim, task = best
         self.sched.steals += 1
-        self.sched.stolen_tokens += task.l_incr
         if self.record_decisions:
             self.decision_log.append((task.session_id, task.round_idx,
                                       task.incr_offset, "steal", thief.idx))
@@ -520,7 +515,6 @@ class Coordinator:
             return None
         _, task, dest = best
         self.sched.migrations += 1
-        self.sched.migrated_tokens += task.l_incr
         if self.record_decisions:
             self.decision_log.append((task.session_id, task.round_idx,
                                       task.incr_offset, "migrate", dest.idx))

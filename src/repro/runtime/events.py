@@ -3,9 +3,6 @@
 One heap, one clock.  The modeled backend advances the clock by predicted
 durations; the live backend advances it by wall-clock-measured engine times —
 either way the protocol engine above sees the same ``at(t, fn)`` interface.
-
-Optional event tracing keeps a bounded log of (time, label) pairs for
-debugging scheduling decisions without paying for it in normal runs.
 """
 from __future__ import annotations
 
@@ -14,15 +11,11 @@ from typing import Callable, List, Optional, Tuple
 
 
 class EventLoop:
-    def __init__(self, max_time: float = float("inf"), *,
-                 trace: bool = False, trace_cap: int = 10_000):
+    def __init__(self, max_time: float = float("inf")):
         self.now = 0.0
         self.max_time = max_time
         self._heap: List[Tuple[float, int, Callable[[], None], Optional[str]]] = []
         self._seq = 0
-        self.tracing = trace
-        self.trace_cap = trace_cap
-        self.trace: List[Tuple[float, str]] = []
 
     def at(self, t: float, fn: Callable[[], None],
            label: Optional[str] = None) -> None:
@@ -41,11 +34,9 @@ class EventLoop:
     def run(self) -> float:
         """Drain the heap; returns the final clock value."""
         while self._heap:
-            t, _, fn, label = heapq.heappop(self._heap)
+            t, _, fn, _ = heapq.heappop(self._heap)
             if t > self.max_time:
                 break
             self.now = max(self.now, t)
-            if self.tracing and label and len(self.trace) < self.trace_cap:
-                self.trace.append((self.now, label))
             fn()
         return self.now

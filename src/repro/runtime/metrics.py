@@ -24,12 +24,9 @@ class SchedCounters:
     """
 
     steals: int = 0            # queued chunks migrated to a draining worker
-    steal_rejected: int = 0    # steal scans where no move was net-positive
     preempts: int = 0          # parked remainders overtaken by higher priority
-    stolen_tokens: int = 0     # sum of l_incr over migrated chunks
     # -- decode-local offload (DESIGN.md §14) ---------------------------
     migrations: int = 0        # local chunks shipped off a saturated decode
-    migrated_tokens: int = 0   # sum of l_incr over offloaded chunks
     offload_rejected: int = 0  # saturated scans where no move was profitable
     # -- global KV pool (DESIGN.md §17) ---------------------------------
     cache_hits: int = 0        # chunks that launched with a resident prefix

@@ -60,6 +60,7 @@ from repro.core.types import PrefillTask
 from repro.runtime.backend import ExecutionBackend, WorkerDiedError
 from repro.runtime.coordinator import Coordinator
 from repro.runtime.events import EventLoop
+from repro.runtime.tracing import span
 
 #: default sub-chunk size when the ampd-chunked scheduler is selected
 #: without an explicit chunk_tokens (≈ one decode-step-bounded pause)
@@ -70,12 +71,12 @@ class ServingRuntime:
     def __init__(self, backend: ExecutionBackend, coordinator: Coordinator,
                  prefill_workers: List, decode_workers: List, *,
                  chunk_tokens: int = 0, max_time: float = float("inf"),
-                 admission_retry_s: float = 0.05, trace_events: bool = False):
+                 admission_retry_s: float = 0.05):
         self.backend = backend
         self.coordinator = coordinator
         self.prefill_workers = prefill_workers
         self.decode_workers = decode_workers
-        self.events = EventLoop(max_time, trace=trace_events)
+        self.events = EventLoop(max_time)
         self.sessions: Dict[int, object] = {}   # id -> session (never index)
         self.admission_retry_s = admission_retry_s
         self.chunk_tokens = chunk_tokens or (
@@ -119,6 +120,10 @@ class ServingRuntime:
         w._rt_offload_hot = False       # offload schmitt trigger state (§14)
         if not hasattr(w, "util_busy_s"):
             w.util_busy_s = 0.0
+        # launches of queued work and the queue wait before each; completed
+        # steps and how long each completion ran past its due time
+        w.launches, w.queue_wait_s = 0, 0.0
+        w.steps, w.lag_s = 0, 0.0
         if not hasattr(w, "tasks_done"):
             w.tasks_done = 0
         if not hasattr(w, "chunk_tokens"):
@@ -245,7 +250,10 @@ class ServingRuntime:
             return
         # full list: Alg. 1 skips dead/ineligible workers itself; the
         # decision names its worker by STABLE id
-        dec = self.coordinator.route(task, self.now, d, self.prefill_workers)
+        with span("repro.route", session=task.session_id,
+                  round=task.round_idx):
+            dec = self.coordinator.route(task, self.now, d,
+                                         self.prefill_workers)
         task.enqueue_time = self.now
         s.state = "prefill_wait"
         if dec.kind == "local":
@@ -289,7 +297,8 @@ class ServingRuntime:
         if w._running:
             return
         while w.prefill_queue:
-            self.coordinator.order_queue(w, self.now)
+            with span("repro.order_queue"):
+                self.coordinator.order_queue(w, self.now)
             task = w.prefill_queue.pop(0)
             s = self.sessions[task.session_id]
             if task.gen != s._rt_gen:       # superseded by a rebind
@@ -313,12 +322,13 @@ class ServingRuntime:
                     w._running = True
                     w.util_busy_s += dur
                     s._rt_chain_worker = (w.kind, w.idx)
-                    self.events.after(
-                        dur,
+                    due = self.now + dur
+                    self.events.at(
+                        due,
                         lambda w=w, task=task, payload=payload, batch=batch,
-                               toks=toks:
+                               toks=toks, due=due:
                             self._on_fused_done(w, task, payload, batch,
-                                                toks),
+                                                toks, due),
                         "fused-step")
                     self._post_launch(w, task)
                     return
@@ -337,10 +347,11 @@ class ServingRuntime:
             w._running = True
             w.util_busy_s += dur + extra
             s._rt_chain_worker = (w.kind, w.idx)
-            self.events.after(
-                extra + dur,
-                lambda w=w, task=task, payload=payload:
-                    self._on_prefill_done(w, task, payload),
+            due = self.now + (extra + dur)
+            self.events.at(
+                due,
+                lambda w=w, task=task, payload=payload, due=due:
+                    self._on_prefill_done(w, task, payload, due),
                 "prefill-done")
             self._post_launch(w, task)
             return
@@ -354,6 +365,8 @@ class ServingRuntime:
         let a prefill worker whose queue fell below the watermark prefetch
         backlog from a more loaded peer before it next idles (watermark 0 =
         no prefetch; steal only on idle)."""
+        w.launches += 1
+        w.queue_wait_s += self.now - task.enqueue_time
         w._rt_running_task = task
         st = self.coordinator.stealing
         if (st is not None and w.kind == "prefill"
@@ -368,9 +381,10 @@ class ServingRuntime:
         if (self.coordinator.stealing is None or w.kind != "prefill"
                 or not w.alive):
             return False
-        plan = self.coordinator.plan_steal(
-            w, self.prefill_workers, self.now, self.sessions,
-            self.decode_workers)
+        with span("repro.steal_scan"):
+            plan = self.coordinator.plan_steal(
+                w, self.prefill_workers, self.now, self.sessions,
+                self.decode_workers)
         if plan is None:
             return False
         victim, task = plan
@@ -395,8 +409,9 @@ class ServingRuntime:
         while True:
             batch = [b for b in self.backend.attached(d)
                      if getattr(b, "state", "") == "decoding"]
-            plan = self.coordinator.plan_offload(
-                d, self.prefill_workers, self.now, self.sessions, batch)
+            with span("repro.offload_scan"):
+                plan = self.coordinator.plan_offload(
+                    d, self.prefill_workers, self.now, self.sessions, batch)
             if plan is None:
                 return
             task, w = plan
@@ -468,7 +483,18 @@ class ServingRuntime:
             pm.finish_chunk((w.kind, w.idx), task.cache_plan)
 
     # -- prefill completion, write-back, decode join (§3 step 3) ------------
-    def _on_prefill_done(self, w, task: PrefillTask, payload) -> None:
+    def _settle(self, w, due: float) -> None:
+        """A step launched on ``w`` completed: count it, and how long its
+        completion ran past ``due`` (launch time plus the duration the
+        backend returned).  That is 0 on the logical loop by construction;
+        on a wall-clock loop it is the time the finished step waited for
+        the host thread."""
+        w.steps += 1
+        w.lag_s += self.now - due
+
+    def _on_prefill_done(self, w, task: PrefillTask, payload,
+                         due: float) -> None:
+        self._settle(w, due)
         w._running = False
         w._rt_running_task = None
         w.tasks_done += 1
@@ -559,10 +585,13 @@ class ServingRuntime:
             self._on_rpc_death(e, d, None, None)
             return
         d.util_busy_s += dur
-        self.events.after(
-            dur, lambda: self._on_step_end(d, batch, toks), "decode-step")
+        due = self.now + dur
+        self.events.at(
+            due, lambda: self._on_step_end(d, batch, toks, due),
+            "decode-step")
 
-    def _on_step_end(self, d, batch: List, toks: Dict) -> None:
+    def _on_step_end(self, d, batch: List, toks: Dict, due: float) -> None:
+        self._settle(d, due)
         d._running = False
         if not d.alive:
             return
@@ -590,9 +619,10 @@ class ServingRuntime:
         return finished
 
     def _on_fused_done(self, d, task: PrefillTask, payload, batch: List,
-                       toks: Dict) -> None:
+                       toks: Dict, due: float) -> None:
         """A fused chunk+decode step ended: settle the decode tokens, then
         land the chunk (local write-back is free)."""
+        self._settle(d, due)
         d._running = False
         d._rt_running_task = None
         d.tasks_done += 1

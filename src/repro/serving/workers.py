@@ -25,6 +25,7 @@ from repro.configs.base import ModelConfig
 from repro.core.types import PrefillTask, RoundSpec
 from repro.models.packed import supports_packed
 from repro.runtime.metrics import WindowStat
+from repro.runtime.tracing import span
 from repro.serving.engine import Engine, chunk_limit
 from repro.serving.kv_transfer import (
     extract_range,
@@ -91,6 +92,22 @@ def timed(fn, *args, **kw):
     return time.perf_counter() - t0, out
 
 
+def kv_op(worker, name: str, fn, *args):
+    """One KV operation under the span ``name``, run to the completion of
+    the tree it produces; its wall time adds to ``worker.kv_s``."""
+    with span(name):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        worker.kv_s += time.perf_counter() - t0
+    return out
+
+
+def sample(logits) -> np.ndarray:
+    """Greedy next tokens of a step's logits, on the host."""
+    with span("repro.decode.sample"):
+        return np.asarray(jnp.argmax(logits, axis=-1))
+
+
 class WorkerSchedState:
     """The scheduling-facing worker surface — the ONLY fields the
     Coordinator and ServingRuntime read or write on a worker, shared by
@@ -111,6 +128,7 @@ class WorkerSchedState:
         self.windowed_itl = 0.0
         self.busy_until = 0.0
         self.kv_bytes_moved = 0
+        self.kv_s = 0.0                 # wall time of KV reads/inserts/extracts
 
 
 class SlotBookkeeping:
@@ -174,22 +192,30 @@ class LivePrefillWorker(WorkerSchedState):
         eng = self.engine
         tokens = chunk_tokens_of(task, session)
         if history_extract is not None and task.l_hist > 0:
-            cache = eng.new_cache(1)
-            cache = insert_range(cache, reshard(history_extract, eng.device),
-                                 eng.cfg, eng.max_len, 0, 0,
-                                 replace_state=True)
+            cache = kv_op(
+                self, "repro.kv.insert_history", lambda: insert_range(
+                    eng.new_cache(1), reshard(history_extract, eng.device),
+                    eng.cfg, eng.max_len, 0, 0, replace_state=True))
             self.kv_bytes_moved += transfer_bytes(history_extract)
             lim = chunk_limit(eng.cfg, eng.max_len)
             logits = None
             for lo in range(0, len(tokens), lim):
-                chunk = eng.pad_chunk(tokens[lo:lo + lim])
-                cache, logits, _ = eng.run_chunk(cache, chunk)
+                with span("repro.prefill.chunk"):
+                    chunk = eng.pad_chunk(tokens[lo:lo + lim])
+                    cache, logits, _ = eng.run_chunk(cache, chunk)
         else:
-            cache, logits = eng.prefill(tokens, cross_embeds=cross_embeds)
-        incr = extract_range(cache, eng.cfg, eng.max_len,
-                             task.l_hist, task.l_hist + task.l_incr)
+            with span("repro.prefill.chunk"):
+                cache, logits = eng.prefill(tokens, cross_embeds=cross_embeds)
+        # the extract queues behind the chunks, its dispatch hidden under
+        # them; its KV time is what it adds once the logits are in hand
+        with span("repro.kv.extract"):
+            incr = extract_range(cache, eng.cfg, eng.max_len,
+                                 task.l_hist, task.l_hist + task.l_incr)
+        with span("repro.prefill.logits"):
+            logits = np.asarray(logits)
+        incr = kv_op(self, "repro.kv.extract", lambda: incr)
         self.kv_bytes_moved += transfer_bytes(incr)
-        return {"increment": incr, "logits": np.asarray(logits)}
+        return {"increment": incr, "logits": logits}
 
 
 class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
@@ -213,15 +239,19 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         #: fused-step telemetry for LiveResult / fig14
         self.fused_steps = 0
         self.fused_s = 0.0
+        #: wall time of decode/fused steps outside ``repro.decode.wait``:
+        #: building the feed, dispatching, sampling
+        self.host_s = 0.0
 
     # -- slot management (free/occupancy/allocate/detach: SlotBookkeeping) --
     def reset_slot(self, slot: int) -> None:
         """Wipe a slot's cache row (lengths, positions, state) before reuse —
         stale positions from a previous occupant must never look valid."""
-        fresh = self.engine.new_cache(1)
-        self.cache = insert_range(self.cache, fresh, self.engine.cfg,
-                                  self.engine.max_len, 0, slot,
-                                  replace_state=True)
+        with span("repro.slot.reset"):
+            fresh = self.engine.new_cache(1)
+            self.cache = insert_range(self.cache, fresh, self.engine.cfg,
+                                      self.engine.max_len, 0, slot,
+                                      replace_state=True)
 
     def attach(self, session: LiveSession, increment: Dict, lo: int,
                first_token: int, n_tokens: int) -> None:
@@ -230,10 +260,10 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         uniform across local and remote placement."""
         if session.slot is None:
             self.allocate(session)
-        self.cache = insert_range(self.cache,
-                                  reshard(increment, self.engine.device),
-                                  self.engine.cfg, self.engine.max_len,
-                                  lo, session.slot, replace_state=True)
+        eng = self.engine
+        self.cache = kv_op(self, "repro.kv.attach", lambda: insert_range(
+            self.cache, reshard(increment, eng.device), eng.cfg, eng.max_len,
+            lo, session.slot, replace_state=True))
         session.last_token = first_token
 
     def history_extract(self, session: LiveSession) -> Dict:
@@ -248,6 +278,16 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
                              lo, hi, row=session.slot)
 
     # -- execution ---------------------------------------------------------
+    def _launch(self, step, *args):
+        """Dispatch one step program and wait for every output; returns
+        (outputs, seconds waited)."""
+        with span("repro.decode.launch"):
+            out = step(*args)
+        with span("repro.decode.wait"):
+            t0 = time.perf_counter()
+            jax.block_until_ready(out)
+            return out, time.perf_counter() - t0
+
     def decode_once(self):
         """One continuous-batching step over all occupied slots.
 
@@ -257,38 +297,40 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         if not occupied:
             return 0.0, {}
 
-        # the (B, 1) decode rectangle is only worth packing when occupancy is
-        # low enough that the shape-bucketed stream is strictly smaller —
-        # at full occupancy the rectangle is already waste-free, while a
-        # pack pays bucket padding plus the per-token row gather
         eng = self.engine
-        if self.packed:
-            from repro.kernels.ragged_fused.ops import pack_layout
-            _, total = pack_layout([1] * len(occupied), eng.pack_align)
-            if eng.packed_bucket(total) < self.max_slots:
-                segs = [(i, np.asarray([self.slots[i].last_token], np.int32))
-                        for i in occupied]
-
-                def pcall():
-                    return eng.run_packed(self.cache, segs)
-
-                dt, (self.cache, seg_logits, _) = timed(pcall)
-                nxt = np.asarray(jnp.argmax(seg_logits, axis=-1))
-                return dt, {slot: int(nxt[j])
-                            for j, slot in enumerate(occupied)}
-
-        tokens = np.full((self.max_slots, 1), -1, np.int32)
-        for i in occupied:
-            tokens[i, 0] = self.slots[i].last_token
-        self.engine.tokens_uploaded += self.max_slots
-
-        def call():
-            cache, logits = self.engine.decode_step(self.cache, jnp.asarray(tokens))
-            return cache, logits
-
-        dt, (self.cache, logits) = timed(call)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        return dt, {i: int(nxt[i]) for i in occupied}
+        with span("repro.decode.step", rows=len(occupied)):
+            t0 = time.perf_counter()
+            # the (B, 1) decode rectangle is only worth packing when
+            # occupancy is low enough that the shape-bucketed stream is
+            # strictly smaller — at full occupancy the rectangle is already
+            # waste-free, while a pack pays bucket padding plus the
+            # per-token row gather
+            packed = False
+            if self.packed:
+                from repro.kernels.ragged_fused.ops import pack_layout
+                _, total = pack_layout([1] * len(occupied), eng.pack_align)
+                packed = eng.packed_bucket(total) < self.max_slots
+            if packed:
+                with span("repro.decode.feed"):
+                    segs = [(i, np.asarray([self.slots[i].last_token],
+                                           np.int32)) for i in occupied]
+                (self.cache, logits, _), wait = self._launch(
+                    eng.run_packed, self.cache, segs)
+                rows = range(len(occupied))
+            else:
+                with span("repro.decode.feed"):
+                    tokens = np.full((self.max_slots, 1), -1, np.int32)
+                    for i in occupied:
+                        tokens[i, 0] = self.slots[i].last_token
+                    eng.tokens_uploaded += self.max_slots
+                    tokens = jnp.asarray(tokens)
+                (self.cache, logits), wait = self._launch(
+                    eng.decode_step, self.cache, tokens)
+                rows = occupied
+            dt = time.perf_counter() - t0
+            nxt = sample(logits)
+            self.host_s += time.perf_counter() - t0 - wait
+        return dt, {slot: int(nxt[r]) for slot, r in zip(occupied, rows)}
 
     def local_prefill(self, task: PrefillTask, session: LiveSession):
         """Execute a prefill in-batch on this decode worker (pauses decode):
@@ -308,60 +350,59 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         eng = self.engine
         tokens = chunk_tokens_of(task, session)
         lim = chunk_limit(eng.cfg, eng.max_len)
-        total_dt = 0.0
+        total_dt = total_wait = 0.0
         toks: Dict[int, int] = {}
 
-        if self.packed:
-            # ragged path: the sub-chunk and the decode rows pack into one
-            # flat stream — chunk + batch tokens of compute, no rectangle.
-            last_logits = None
+        with span("repro.fused", session=task.session_id,
+                  round=task.round_idx, rows=len(batch) + 1):
+            t0 = time.perf_counter()
             for lo in range(0, len(tokens), lim):
-                sub = np.asarray(tokens[lo:lo + lim], np.int32)
-                segs = [(session.slot, sub)]
-                if lo == 0:      # decode rows advance once per fused step
-                    segs += [(s.slot, np.asarray([s.last_token], np.int32))
-                             for s in batch]
-
-                def pcall(sg=segs):
-                    return eng.run_packed(self.cache, sg)
-
-                dt, (self.cache, seg_logits, _) = timed(pcall)
-                total_dt += dt
+                t1 = time.perf_counter()
+                if self.packed:
+                    # ragged path: the sub-chunk and the decode rows pack
+                    # into one flat stream — chunk + batch tokens of
+                    # compute, no rectangle.
+                    with span("repro.decode.feed"):
+                        segs = [(session.slot,
+                                 np.asarray(tokens[lo:lo + lim], np.int32))]
+                        if lo == 0:  # decode rows advance once per step
+                            segs += [(s.slot,
+                                      np.asarray([s.last_token], np.int32))
+                                     for s in batch]
+                    (self.cache, seg_logits, _), wait = self._launch(
+                        eng.run_packed, self.cache, segs)
+                    chunk_logits = seg_logits[0]
+                    batch_logits = seg_logits[1:]
+                    rows = range(len(batch))
+                else:
+                    with span("repro.decode.feed"):
+                        sub = tokens[lo:lo + lim]
+                        m = eng.pad_mult
+                        width = ((len(sub) + m - 1) // m) * m
+                        row = np.full((width,), -1, np.int32)
+                        row[:len(sub)] = sub
+                        feed = np.full((self.max_slots,), -1, np.int32)
+                        if lo == 0:  # decode rows advance once per step
+                            for s in batch:
+                                feed[s.slot] = s.last_token
+                        # non-advancing rows stay -1 in every sub-chunk: the
+                        # matrix is composed on device from width +
+                        # max_slots uploaded elements, never the max_slots x
+                        # width rectangle (DESIGN.md §15).
+                        c = eng.compose_fused_chunk(row, session.slot, feed)
+                    (self.cache, logits, _), wait = self._launch(
+                        eng.run_chunk, self.cache, c)
+                    chunk_logits = logits[session.slot]
+                    batch_logits = logits
+                    rows = [s.slot for s in batch]
+                total_dt += time.perf_counter() - t1
+                total_wait += wait
                 if lo == 0 and batch:
-                    nxt = np.asarray(jnp.argmax(seg_logits[1:], axis=-1))
-                    toks = {s.session_id: int(nxt[j])
-                            for j, s in enumerate(batch)}
-                last_logits = seg_logits[0]
-            self.fused_steps += 1
-            self.fused_s += total_dt
-            return (total_dt,
-                    int(np.asarray(jnp.argmax(last_logits))), toks)
-
-        logits = None
-        for lo in range(0, len(tokens), lim):
-            sub = tokens[lo:lo + lim]
-            m = eng.pad_mult
-            width = ((len(sub) + m - 1) // m) * m
-            row = np.full((width,), -1, np.int32)
-            row[:len(sub)] = sub
-            feed = np.full((self.max_slots,), -1, np.int32)
-            if lo == 0:          # decode rows advance once per fused step
-                for s in batch:
-                    feed[s.slot] = s.last_token
-            # non-advancing rows stay -1 in every sub-chunk: the matrix is
-            # composed on device from width + max_slots uploaded elements,
-            # never the max_slots x width rectangle (DESIGN.md §15).
-
-            def call(r=row, f=feed):
-                c = eng.compose_fused_chunk(r, session.slot, f)
-                return eng.run_chunk(self.cache, c)
-
-            dt, (self.cache, logits, _) = timed(call)
-            total_dt += dt
-            if lo == 0:
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
-                toks = {s.session_id: int(nxt[s.slot]) for s in batch}
+                    nxt = sample(batch_logits)
+                    toks = {s.session_id: int(nxt[r])
+                            for s, r in zip(batch, rows)}
+            first = int(sample(chunk_logits))
+            self.host_s += time.perf_counter() - t0 - total_wait
         self.fused_steps += 1
         self.fused_s += total_dt
-        return (total_dt,
-                int(np.asarray(jnp.argmax(logits[session.slot]))), toks)
+        return total_dt, first, toks
