@@ -56,6 +56,13 @@ def attention(
 ) -> jax.Array:
     if impl == "auto":
         impl = "flash" if _kernel_available() else "ref"
+    if impl == "flash" and causal and q.shape[1] == 1:
+        # one token per row: read each row's live KV once, in the cache's
+        # own layout, instead of the prefill kernel over every position
+        from repro.kernels.decode_attn import ops as decode_ops
+        return decode_ops.decode_attention(
+            q, k, v, q_positions=q_positions, kv_positions=kv_positions,
+            window=window, attn_softcap=attn_softcap, scale=scale)
     if impl == "flash":
         from repro.kernels.flash_prefill import ops as flash_ops
         return flash_ops.flash_attention(

@@ -242,6 +242,10 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
         #: wall time of decode/fused steps outside ``repro.decode.wait``:
         #: building the feed, dispatching, sampling
         self.host_s = 0.0
+        #: occupied rows' cached context summed over dense decode steps:
+        #: the cache positions the decode kernel reads (of max_slots x
+        #: max_len per step)
+        self.decode_ctx_tokens = 0
 
     # -- slot management (free/occupancy/allocate/detach: SlotBookkeeping) --
     def reset_slot(self, slot: int) -> None:
@@ -324,6 +328,8 @@ class LiveDecodeWorker(WorkerSchedState, SlotBookkeeping):
                         tokens[i, 0] = self.slots[i].last_token
                     eng.tokens_uploaded += self.max_slots
                     tokens = jnp.asarray(tokens)
+                    self.decode_ctx_tokens += sum(
+                        self.slots[i].context_len for i in occupied)
                 (self.cache, logits), wait = self._launch(
                     eng.decode_step, self.cache, tokens)
                 rows = occupied

@@ -12,7 +12,9 @@ or a parametrize), and only the xdist worker given this file loads the TPU
 library.  The ops wrappers take their kernel branch only on a ``tpu``
 backend, so each test patches ``jax.default_backend`` for its own trace.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +136,32 @@ def test_served_step_compiles_at_full_width(one_chip, tpu_backend, cfg,
         args = (params, cache, _shape(one_chip, (B, 1)))
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < 16e9, used
+
+
+def test_dense_decode_step_runs_decode_kernel_at_qwen_width(one_chip,
+                                                            tpu_backend):
+    """The one-token step at the benchmark's qwen2.5-14b widths (8 of 48
+    layers, 8 slots of 8192): its attention is the decode kernel, with no
+    prefill kernel left in the step, and it fits one chip."""
+    qwen = dataclasses.replace(get_config("qwen2.5-14b"), num_layers=8)
+    slots, max_len = 8, 8192
+
+    def place(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype),
+                            tree)
+
+    params = place(jax.eval_shape(build_model(qwen).init,
+                                  jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: init_cache(qwen, slots, max_len)))
+    compiled = jax.jit(functools.partial(forward_cached, qwen),
+                       donate_argnums=(1,)).lower(
+        params, cache, _shape(one_chip, (slots, 1))).compile()
+    text = compiled.as_text()
+    assert re.search(r"%decode_attention[.\d]* = .*custom-call", text)
+    assert "%flash_attention" not in text
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16e9, used

@@ -58,7 +58,38 @@ DEC_SWEEP = [
     (2, 10, 1, 128, 256, 100, None, None),    # MQA (recurrentgemma-like)
     (1, 64, 8, 112, 256, 60, None, None),     # kimi head_dim
     (2, 24, 24, 64, 128, 90, None, None),     # MHA (musicgen)
+    # per-row last positions (-1: empty slot): empty, mid-block, on a
+    # block boundary (128-position blocks), full
+    (4, 40, 8, 128, 512, (-1, 299, 255, 511), None, None),   # qwen widths
+    (4, 32, 8, 128, 512, (511, -1, 127, 200), None, None),   # mixtral widths
+    (3, 16, 4, 64, 384, (383, 130, -1), 64, None),   # hd 64: padded lanes
+    # a ring of 256 plus the new token (LOCAL layers): one row wrapped
+    # (positions not contiguous in the cache), one not yet full
+    (2, 8, 2, 128, 257, ("ring", 600, 100), 256, None),
 ]
+
+
+def _dec_positions(B, T, pos):
+    """(q_positions (B, 1), kv_positions (B, T)) for a DEC_SWEEP ``pos``:
+    one last position for every row, one per row (-1 for an empty slot),
+    or ("ring", *last) for a ring of T-1 slots followed by the new token."""
+    inv = -(2 ** 30)
+    if isinstance(pos, int):
+        pos = (pos,) * B
+    kpos = np.full((B, T), inv, np.int32)
+    qpos = np.full((B, 1), inv, np.int32)
+    if pos[0] == "ring":
+        W = T - 1
+        for b, p in enumerate(pos[1:]):
+            hist = np.arange(max(0, p - W), p)
+            kpos[b, hist % W] = hist
+            kpos[b, W] = qpos[b, 0] = p
+        return jnp.asarray(qpos), jnp.asarray(kpos)
+    for b, p in enumerate(pos):
+        if p >= 0:
+            kpos[b, :p + 1] = np.arange(p + 1)
+            qpos[b, 0] = p
+    return jnp.asarray(qpos), jnp.asarray(kpos)
 
 
 @pytest.mark.parametrize("B,H,G,hd,T,pos,window,softcap", DEC_SWEEP)
@@ -67,9 +98,7 @@ def test_decode_attn_vs_oracle(B, H, G, hd, T, pos, window, softcap):
     q = jax.random.normal(ks[0], (B, 1, H, hd))
     k = jax.random.normal(ks[1], (B, T, G, hd))
     v = jax.random.normal(ks[2], (B, T, G, hd))
-    qpos = jnp.full((B, 1), pos, jnp.int32)
-    kpos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    kpos = jnp.where(kpos <= pos, kpos, -(2 ** 30))
+    qpos, kpos = _dec_positions(B, T, pos)
     scale = hd ** -0.5
     o1 = decode_attention(q, k, v, q_positions=qpos, kv_positions=kpos,
                           window=window, attn_softcap=softcap, scale=scale,
@@ -79,25 +108,42 @@ def test_decode_attn_vs_oracle(B, H, G, hd, T, pos, window, softcap):
                           force_ref=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=5e-5,
                                rtol=5e-5)
+    empty = np.asarray(qpos[:, 0]) < 0
+    assert not np.asarray(o1)[empty].any() and not np.asarray(o2)[empty].any()
 
 
-def test_decode_residual_combine():
+def test_live_blocks_stop_at_each_rows_last_valid_position():
+    from repro.kernels.decode_attn.ops import live_blocks
+    qpos, kpos = _dec_positions(4, 512, (-1, 299, 255, 511))
+    assert live_blocks(qpos, kpos, 128).tolist() == [0, 3, 2, 4]
+    qpos, kpos = _dec_positions(2, 257, ("ring", 600, 100))
+    assert live_blocks(qpos, kpos, 128).tolist() == [3, 3]
+    # a row whose query is padding reads nothing, whatever its cache holds
+    assert live_blocks(jnp.full((2, 1), -(2 ** 30), jnp.int32), kpos,
+                       128).tolist() == [0, 0]
+
+
+@pytest.mark.parametrize("pos", [230, 100])     # 100: shard 2 holds nothing
+@pytest.mark.parametrize("kernel", [False, True])
+def test_decode_residual_combine(pos, kernel):
     """Flash-decoding shard combine reproduces the unsharded result."""
     B, H, G, hd, T = 2, 8, 2, 64, 256
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, 1, H, hd))
     k = jax.random.normal(ks[1], (B, T, G, hd))
     v = jax.random.normal(ks[2], (B, T, G, hd))
-    qpos = jnp.full((B, 1), 230, jnp.int32)
-    kpos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    kpos = jnp.where(kpos <= 230, kpos, -(2 ** 30))
+    qpos, kpos = _dec_positions(B, T, pos)
+    mode = dict(block_kv=128, interpret=True) if kernel else dict(
+        force_ref=True)
     full = decode_attention(q, k, v, q_positions=qpos, kv_positions=kpos,
                             scale=hd ** -0.5, force_ref=True)
     parts = []
     for sl in (slice(0, 128), slice(128, 256)):
         parts.append(decode_attention(
             q, k[:, sl], v[:, sl], q_positions=qpos, kv_positions=kpos[:, sl],
-            scale=hd ** -0.5, force_ref=True, return_residuals=True))
+            scale=hd ** -0.5, return_residuals=True, **mode))
+    if pos < 128:
+        assert not np.asarray(parts[1][2]).any()
     m_star = jnp.maximum(parts[0][1], parts[1][1])
     w = [p[2] * jnp.exp(p[1] - m_star) for p in parts]
     den = w[0] + w[1]
@@ -105,6 +151,32 @@ def test_decode_residual_combine():
            + parts[1][0].astype(jnp.float32) * w[1][:, None, :, None])
     comb = num / den[:, None, :, None]
     np.testing.assert_allclose(np.asarray(comb), np.asarray(full), atol=1e-5)
+
+
+@pytest.mark.parametrize("S,kernel", [(1, "decode_attention"),
+                                      (4, "flash_attention")])
+def test_flash_impl_dispatches_by_query_length(monkeypatch, S, kernel):
+    """impl="flash" sends one-token calls to the decode kernel and longer
+    chunks to the prefill kernel."""
+    from repro.kernels.decode_attn import ops as decode_ops
+    from repro.kernels.flash_prefill import ops as flash_ops
+    from repro.models.attention import attention
+
+    called = []
+    for mod, name in ((decode_ops, "decode_attention"),
+                      (flash_ops, "flash_attention")):
+        def spy(*a, _f=getattr(mod, name), _n=name, **kw):
+            called.append(_n)
+            return _f(*a, **kw)
+        monkeypatch.setattr(mod, name, spy)
+    B, H, G, hd, T = 2, 8, 2, 64, 32
+    q, k, v, qpos, kpos = _mk(B, S, H, G, hd, T, T - S, jnp.float32)
+    out = attention(q, k, v, q_positions=qpos, kv_positions=kpos,
+                    scale=hd ** -0.5, impl="flash")
+    ref = attention(q, k, v, q_positions=qpos, kv_positions=kpos,
+                    scale=hd ** -0.5, impl="ref")
+    assert called == [kernel]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
 def test_chunked_attention_vs_dense():

@@ -76,6 +76,11 @@ def _instrument(cl, log):
 
     be.run_prefill, be.run_fused_prefill = prefill, fused
     for d in cl.decode_workers:
+        def dense(*a, _f=d.engine.decode_step, _d=d):
+            log["ctx"].append(sum(s.context_len for s in _d.slots
+                                  if s is not None))
+            return _f(*a)
+        d.engine.decode_step = dense
         for name in ("decode_once", "fused_step"):
             def call(*a, _f=getattr(d, name), _d=d):
                 h0, t0 = _d.host_s, time.perf_counter()
@@ -98,7 +103,7 @@ def served(tmp_path_factory):
     jax.profiler.start_trace(str(out))
     try:
         for name, cl in (("remote", remote), ("local", local)):
-            logs[name] = {"waits": [], "steps": []}
+            logs[name] = {"waits": [], "steps": [], "ctx": []}
             _instrument(cl, logs[name])
             cl.run_trace(make_live_sessions(cfg, num_sessions=3, rounds=2,
                                             prefill_len=16, decode_len=4))
@@ -192,6 +197,17 @@ def test_host_time_within_step_and_kv_time_counted(served):
     # side pays the read, insert and extract; the decode side the attach
     assert any(w.kv_s > 0.0 for w in remote.prefill_workers)
     assert remote.decode_workers[0].kv_s > 0.0
+
+
+def test_decode_ctx_tokens_sums_context_of_dense_steps(served):
+    """The counter adds each dense decode step's occupied rows' cached
+    context, and nothing else."""
+    for key in ("remote", "local"):
+        ctx = served["logs"][key]["ctx"]
+        assert ctx and sum(ctx) > 0, key
+        assert served[key].decode_workers[0].decode_ctx_tokens == sum(ctx)
+    fresh = _cluster(get_config("qwen2.5-14b").reduced())
+    assert fresh.decode_workers[0].decode_ctx_tokens == 0
 
 
 def _simulation():
