@@ -97,7 +97,9 @@ def main(argv=None) -> int:
     def serve(m, seed, seconds):
         sid[0] += 1
         res = harness.serve(cl, c, m, seed, seconds,
-                            sid_base=sid[0] * 1_000_000, finish=True)
+                            sid_base=sid[0] * 1_000_000, finish=True,
+                            counters=harness.declared_counters(
+                                readers.values()))
         run = harness.make_run(res, c, m, cell, seconds, dev.device_kind)
         out = {n: r.read(run) for n, r in readers.items()}
         out["unfinished"] = sum(1 for r in run.rounds

@@ -53,14 +53,14 @@ def _gaps(ref_logits, tokens) -> np.ndarray:
 
 def compare(c: dict, mix: dict, seed: int, served: List[dict],
             search: Sequence, control: bool = False) -> Dict:
-    from bench.harness import find, load_module
+    from bench.model import architecture
 
     spec = mix["check"]
     picked = sample(served, seed, int(spec["sample_tokens"]))
     if not picked:
         return {"correct": False, "numbers": {
             "served_tokens_checked": {"value": 0, "limit": 1}}}
-    ref = load_module(find("configs", c["reference"], ".py", search))
+    ref = architecture(c, search)
     seqs = [s["tokens"] for s in picked]
     pos = [s["positions"] for s in picked]
     logits = ref.logits_at(c, seed, seqs, pos)

@@ -17,21 +17,147 @@ where the program stores bfloat16: every weight (one scale per output
 channel), every activation entering or leaving a projection and the
 residual stream after each add (one scale per token) are rounded to
 float8; accumulation stays float32.
+
+Beside the reference, the architecture's description on the benchmark's
+side: the program's ``ModelConfig``, its parameter tree as leaf groups (one
+stack of identical layers under ``stacked/0``), and the work each layer and
+each attention kernel needs, counted from the published shapes.
 """
 from __future__ import annotations
 
 import functools
-from typing import List, Optional
+import math
+from typing import Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import head_dim, layer_weights, top_weights
+from bench.model import Group, layer_weights, top_weights
 
 F32 = jnp.float32
 Q_BLOCK = 512
 BUCKET = 1024
+
+
+# ---------------------------------------------------------------------------
+# The architecture as the program runs it
+# ---------------------------------------------------------------------------
+
+def head_dim(c: dict) -> int:
+    return int(c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"])
+
+
+def model_config(c: dict):
+    """The program's ``ModelConfig`` for a configuration file's keys."""
+    from repro.configs.base import ATTN, ModelConfig
+
+    experts = int(c.get("num_local_experts") or 0)
+    return ModelConfig(
+        name=c["name"],
+        family="moe" if experts else "dense",
+        num_layers=c["num_hidden_layers"],
+        d_model=c["hidden_size"],
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"],
+        head_dim=head_dim(c),
+        d_ff=c["intermediate_size"],
+        vocab_size=c["vocab_size"],
+        layer_pattern=(ATTN,),
+        qkv_bias=bool(c.get("attention_bias", False)),
+        rope_theta=float(c["rope_theta"]),
+        activation="swiglu",
+        tie_embeddings=bool(c["tie_word_embeddings"]),
+        num_experts=experts,
+        num_experts_per_tok=int(c.get("num_experts_per_tok") or 0),
+        rms_eps=float(c["rms_norm_eps"]),
+        dtype="bfloat16",
+    )
+
+
+def _layer_leaves(c: dict):
+    d, H, G = c["hidden_size"], c["num_attention_heads"], c["num_key_value_heads"]
+    hd, ff = head_dim(c), c["intermediate_size"]
+    out = [
+        ("norm.w", (d,), "float32", ("around", 1.0, 0.25)),
+        ("wq", (d, H, hd), "bfloat16", ("uniform", d)),
+        ("wk", (d, G, hd), "bfloat16", ("uniform", d)),
+        ("wv", (d, G, hd), "bfloat16", ("uniform", d)),
+        ("wo", (H, hd, d), "bfloat16", ("uniform", H * hd)),
+        ("ffn_norm.w", (d,), "float32", ("around", 1.0, 0.25)),
+    ]
+    if c.get("attention_bias"):
+        out += [("bq", (H, hd), "bfloat16", ("around", 0.0, 0.5)),
+                ("bk", (G, hd), "bfloat16", ("around", 0.0, 0.5)),
+                ("bv", (G, hd), "bfloat16", ("around", 0.0, 0.5))]
+    E = int(c.get("num_local_experts") or 0)
+    if E:
+        out += [("moe.router", (d, E), "float32", ("uniform", d)),
+                ("moe.wg", (E, d, ff), "bfloat16", ("uniform", d)),
+                ("moe.wi", (E, d, ff), "bfloat16", ("uniform", d)),
+                ("moe.wo", (E, ff, d), "bfloat16", ("uniform", ff))]
+    else:
+        out += [("mlp.wg", (d, ff), "bfloat16", ("uniform", d)),
+                ("mlp.wi", (d, ff), "bfloat16", ("uniform", d)),
+                ("mlp.wo", (ff, d), "bfloat16", ("uniform", ff))]
+    return tuple(out)
+
+
+def layout(c: dict) -> Sequence[Group]:
+    """The top leaves at the root, and every layer in one stack under
+    ``stacked/0``, as the program scans a one-kind layer pattern."""
+    d, V = c["hidden_size"], c["vocab_size"]
+    top = [("embed", (V, d), "bfloat16", ("around", 0.0, math.sqrt(3.0))),
+           ("final_norm.w", (d,), "float32", ("around", 1.0, 0.25))]
+    if not c["tie_word_embeddings"]:
+        top.append(("unembed", (d, V), "bfloat16", ("uniform", d)))
+    return (Group("", tuple(top)),
+            Group("stacked/0", _layer_leaves(c),
+                  tuple(range(c["num_hidden_layers"]))))
+
+
+# ---------------------------------------------------------------------------
+# Work: operations and bytes from the published shapes (bench/work.py)
+# ---------------------------------------------------------------------------
+
+def _pairs(n: int, h: int) -> int:
+    """Query-key pairs of n causal queries after h cached tokens."""
+    return n * h + n * (n + 1) // 2
+
+
+def attention_work(c: dict, segments: Iterable[Tuple[int, int]],
+                   layer: int = 0) -> Tuple[float, float]:
+    """(FLOPs, bytes) of one attention kernel call over one layer: QK^T and
+    PV for every causal pair; reads q and each row's valid K/V once,
+    writes the output; bf16 operands.  Every layer alike."""
+    H, G, hd = c["num_attention_heads"], c["num_key_value_heads"], head_dim(c)
+    flops = nbytes = 0.0
+    for n, h in segments:
+        flops += 4.0 * H * hd * _pairs(n, h)
+        nbytes += 2.0 * (2 * n * H * hd + 2 * (h + n) * G * hd)
+    return flops, nbytes
+
+
+#: the decode kernel's call (one query per row) needs the same count: each
+#: row's live K/V read once, its q read and its output written
+decode_attention_work = attention_work
+
+
+def layer_matmul_params(c: dict, layer: int = 0) -> int:
+    """Weights one token multiplies by in one layer (top-k experts only);
+    every layer alike."""
+    d, H, G = c["hidden_size"], c["num_attention_heads"], c["num_key_value_heads"]
+    hd, ff = head_dim(c), c["intermediate_size"]
+    attn = d * (H + 2 * G) * hd + H * hd * d
+    E = int(c.get("num_local_experts") or 0)
+    if E:
+        return attn + d * E + 3 * d * ff * int(c["num_experts_per_tok"])
+    return attn + 3 * d * ff
+
+
+# ---------------------------------------------------------------------------
+# The plain reference
+# ---------------------------------------------------------------------------
 
 
 def _q8(x, axis):

@@ -10,8 +10,11 @@ the configuration's plain reference.  The last line of standard output is
 one JSON object; the lines before it, and standard error, carry the rest.
 
 Everything a cell is made of is found by name: ``configs/<config>.json``
-(with its reference ``configs/<reference>.py``), ``traffic/<traffic>.json``
-and ``metrics/<metric>.py``, in the directories of ``search``.
+(with its architecture module ``configs/<reference>.py``, see
+``bench/model.py``), ``traffic/<traffic>.json`` and ``metrics/<metric>.py``,
+in the directories of ``search``.  A reader lists the program counters it
+reads in ``COUNTERS``, each as ``<role>.<attribute>``; the harness takes
+those at the window's edges.
 """
 from __future__ import annotations
 
@@ -77,6 +80,7 @@ def load_module(path: Path):
 def load_config(name: str, search) -> dict:
     c = json.loads(find("configs", name, ".json", search).read_text())
     c["name"] = name
+    c["architecture"] = str(find("configs", c["reference"], ".py", search))
     return c
 
 
@@ -109,7 +113,7 @@ class Run:
     rounds: List[RoundRecord]            # rounds due in [w0, w1)
     all_rounds: List[RoundRecord]
     recorder: object
-    counters: Dict[str, Tuple[float, float]]    # name -> (at w0, at w1)
+    counters: Dict[str, Tuple[float, float]]    # role.name -> (at w0, at w1)
     compile_times: List[float]
     trace: object = None                 # bench.trace.Trace, traced runs
     trace_window: Optional[Tuple[float, float]] = None   # loop seconds
@@ -246,15 +250,25 @@ def warm_up(cl, shapes) -> Dict[str, int]:
 # The measured run
 # ---------------------------------------------------------------------------
 
-def _counters(cl) -> Dict[str, float]:
-    co = cl.coordinator
-    pws = cl.prefill_workers
-    return {
-        "local_count": co.local_count,
-        "total_routed": co.total_routed,
-        "kv_bytes_moved": sum(w.kv_bytes_moved for w in pws),
-        "prefill_busy_s": sum(w.util_busy_s for w in pws),
-    }
+#: the cluster's objects that hold each role's counters
+ROLES = {"coordinator": lambda cl: [cl.coordinator],
+         "prefill": lambda cl: cl.prefill_workers,
+         "decode": lambda cl: cl.decode_workers}
+
+
+def declared_counters(readers) -> List[str]:
+    """Every counter the readers list in ``COUNTERS``."""
+    return sorted({n for r in readers for n in getattr(r, "COUNTERS", ())})
+
+
+def _counters(cl, names: Sequence[str]) -> Dict[str, float]:
+    """Each counter ``<role>.<attribute>``: the attribute summed over the
+    role's objects, 0 on an object that lacks it."""
+    out = {}
+    for n in names:
+        role, attr = n.split(".", 1)
+        out[n] = sum(getattr(o, attr, 0) for o in ROLES[role](cl))
+    return out
 
 
 def _install(cl):
@@ -286,9 +300,11 @@ def _install(cl):
 
 def serve(cl, c: dict, mix: dict, seed: int, seconds: float,
           trace: bool = False, trace_dir: Optional[Path] = None,
-          sid_base: int = 0, finish: bool = False):
-    """Offer the traffic on the wall clock, measure, drain.  ``finish``
-    runs on until every session is done, so the cluster is idle again."""
+          sid_base: int = 0, finish: bool = False,
+          counters: Sequence[str] = ()):
+    """Offer the traffic on the wall clock, measure, drain, taking the
+    ``counters`` at the window's edges.  ``finish`` runs on until every
+    session is done, so the cluster is idle again."""
     import jax
     from repro.core.types import RoundSpec
     from repro.serving.workers import LiveSession
@@ -313,7 +329,7 @@ def serve(cl, c: dict, mix: dict, seed: int, seconds: float,
     never = lambda: False  # noqa: E731
     loop.run_until(never, w0)
     setup_s = process_age_s()
-    at_w0 = _counters(cl)
+    at_w0 = _counters(cl, counters)
     trace_window = None
     if trace:
         rec.annotate = True
@@ -326,7 +342,7 @@ def serve(cl, c: dict, mix: dict, seed: int, seconds: float,
         rec.annotate = False
         trace_window = (t_lo, t_hi)
     loop.run_until(never, w1)
-    at_w1 = _counters(cl)
+    at_w1 = _counters(cl, counters)
 
     # rounds due in the window: round 0 at its arrival, round k at round
     # k-1's last token plus its environment delay
@@ -452,7 +468,7 @@ def main(argv=None, *, require_tpu: bool = True,
         import shutil
         shutil.rmtree(trace_dir, ignore_errors=True)
     res = serve(cl, c, mix, args.seed, args.seconds, bool(args.trace),
-                trace_dir)
+                trace_dir, counters=declared_counters(readers.values()))
     times["ramp_s"] = float(mix["ramp_s"])
     run = make_run(res, c, mix, cell, args.seconds, dev.device_kind)
     device = {"platform": dev.platform, "kind": dev.device_kind,

@@ -1,7 +1,9 @@
 """Roofline time of the flash-prefill kernel's calls in the traced window
-(dense prefill and dense decode steps) over their device time."""
-from bench.layers import kernel_roofline
+(dense prefills and dense fused steps) over the device time of
+``%flash_attention``."""
+from bench.layers import KERNEL_OP, kernel_roofline
 
 
 def read(run):
-    return kernel_roofline(run, "flash")
+    return kernel_roofline(run, KERNEL_OP["flash"], ("flash",),
+                           "attention_work")

@@ -2,7 +2,10 @@
 the decode worker (the packed local step) rather than sending to the
 prefill worker (program counters ``local_count`` / ``total_routed``)."""
 
+COUNTERS = ("coordinator.local_count", "coordinator.total_routed")
+
 
 def read(run):
-    routed = run.delta("total_routed")
-    return 100.0 * run.delta("local_count") / routed if routed else None
+    routed = run.delta("coordinator.total_routed")
+    return (100.0 * run.delta("coordinator.local_count") / routed
+            if routed else None)

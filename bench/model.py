@@ -1,9 +1,13 @@
 """A configuration file as the program runs it, and its weights from a seed.
 
 The configuration file holds the model's published ``config.json`` keys (with
-depth cut as its ``published`` entry records) plus a ``serving`` group.
-``model_config`` turns it into the program's ``ModelConfig``; nothing here
-reads the program's own configuration registry.
+depth cut as its ``published`` entry records), a ``serving`` group, and under
+``reference`` the name of its architecture module, ``configs/<reference>.py``
+beside the file.  That module is the architecture's whole description on the
+benchmark's side: the program's ``ModelConfig`` (``model_config``), the
+program's parameter tree as groups of leaves (:class:`Group`) (``layout``), the
+work each layer and kernel needs (``bench.work``), and the plain reference
+(``logits_at``).  Nothing here names an architecture's keys.
 
 Weights are the benchmark's input, not the program's: every leaf is hashed
 from ``(seed, leaf name, layer)`` and the element index by
@@ -17,7 +21,9 @@ from __future__ import annotations
 import functools
 import hashlib
 import math
-from typing import Callable, Dict, List, Tuple
+from dataclasses import dataclass
+from pathlib import Path
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,79 +31,52 @@ import numpy as np
 
 F32 = jnp.float32
 
+#: one leaf: (flat name, per-layer shape, dtype, init), where ``init`` is
+#: ("uniform", fan_in) or ("around", centre, half-width)
+Leaf = Tuple[str, Tuple[int, ...], str, Tuple]
+
+
+@dataclass(frozen=True)
+class Group:
+    """Leaves at one place of the program's parameter tree.
+
+    ``path`` is the place ('/'-separated, "" for the root).  ``layers``
+    None: the model's top leaves (embedding, final norm, head), keyed as
+    layer 0.  Otherwise the layers whose copies of ``leaves`` sit there:
+    stacked on a leading axis in that order, or, with ``stacked`` False,
+    the one layer held unstacked.
+    """
+    path: str
+    leaves: Tuple[Leaf, ...]
+    layers: Optional[Tuple[int, ...]] = None
+    stacked: bool = True
+
+
+@functools.lru_cache(maxsize=None)
+def _load_architecture(path: str):
+    from bench.harness import load_module
+    return load_module(Path(path))
+
+
+def architecture(c: dict, search: Optional[Sequence] = None):
+    """The module a configuration names under ``reference``: the one that
+    ``harness.load_config`` found beside the configuration file, else
+    ``configs/<reference>.py`` in the directories of ``search`` (the
+    benchmark's own by default)."""
+    path = c.get("architecture")
+    if path is None:
+        from bench.harness import BENCH_DIR, find
+        path = find("configs", c["reference"], ".py", search or (BENCH_DIR,))
+    return _load_architecture(str(path))
+
 
 def model_config(c: dict):
     """The program's ``ModelConfig`` for a configuration file's keys."""
-    from repro.configs.base import ATTN, ModelConfig
-
-    experts = int(c.get("num_local_experts") or 0)
-    return ModelConfig(
-        name=c["name"],
-        family="moe" if experts else "dense",
-        num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=head_dim(c),
-        d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"],
-        layer_pattern=(ATTN,),
-        qkv_bias=bool(c.get("attention_bias", False)),
-        rope_theta=float(c["rope_theta"]),
-        activation="swiglu",
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        num_experts=experts,
-        num_experts_per_tok=int(c.get("num_experts_per_tok") or 0),
-        rms_eps=float(c["rms_norm_eps"]),
-        dtype="bfloat16",
-    )
+    return architecture(c).model_config(c)
 
 
-def head_dim(c: dict) -> int:
-    return int(c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"])
-
-
-# ---------------------------------------------------------------------------
-# Leaves: (name, per-layer shape, dtype, init) in the program's tree layout
-# ---------------------------------------------------------------------------
-
-def layer_leaves(c: dict) -> List[Tuple[str, Tuple[int, ...], str, Tuple]]:
-    """Per-layer leaves; ``init`` is ("uniform", fan_in) or ("around", centre,
-    half-width)."""
-    d, H, G = c["hidden_size"], c["num_attention_heads"], c["num_key_value_heads"]
-    hd, ff = head_dim(c), c["intermediate_size"]
-    out = [
-        ("norm.w", (d,), "float32", ("around", 1.0, 0.25)),
-        ("wq", (d, H, hd), "bfloat16", ("uniform", d)),
-        ("wk", (d, G, hd), "bfloat16", ("uniform", d)),
-        ("wv", (d, G, hd), "bfloat16", ("uniform", d)),
-        ("wo", (H, hd, d), "bfloat16", ("uniform", H * hd)),
-        ("ffn_norm.w", (d,), "float32", ("around", 1.0, 0.25)),
-    ]
-    if c.get("attention_bias"):
-        out += [("bq", (H, hd), "bfloat16", ("around", 0.0, 0.5)),
-                ("bk", (G, hd), "bfloat16", ("around", 0.0, 0.5)),
-                ("bv", (G, hd), "bfloat16", ("around", 0.0, 0.5))]
-    E = int(c.get("num_local_experts") or 0)
-    if E:
-        out += [("moe.router", (d, E), "float32", ("uniform", d)),
-                ("moe.wg", (E, d, ff), "bfloat16", ("uniform", d)),
-                ("moe.wi", (E, d, ff), "bfloat16", ("uniform", d)),
-                ("moe.wo", (E, ff, d), "bfloat16", ("uniform", ff))]
-    else:
-        out += [("mlp.wg", (d, ff), "bfloat16", ("uniform", d)),
-                ("mlp.wi", (d, ff), "bfloat16", ("uniform", d)),
-                ("mlp.wo", (ff, d), "bfloat16", ("uniform", ff))]
-    return out
-
-
-def top_leaves(c: dict) -> List[Tuple[str, Tuple[int, ...], str, Tuple]]:
-    d, V = c["hidden_size"], c["vocab_size"]
-    out = [("embed", (V, d), "bfloat16", ("around", 0.0, math.sqrt(3.0))),
-           ("final_norm.w", (d,), "float32", ("around", 1.0, 0.25))]
-    if not c["tie_word_embeddings"]:
-        out.append(("unembed", (d, V), "bfloat16", ("uniform", d)))
-    return out
+def layout(c: dict) -> Sequence[Group]:
+    return architecture(c).layout(c)
 
 
 def leaf_key(seed: int, name: str, layer: int) -> np.ndarray:
@@ -138,47 +117,64 @@ def _leaf(key, *, shape, dtype, init):
     return leaf_values(key, shape, dtype, init)
 
 
-def layer_weights(c: dict, seed: int, layer: int) -> Dict[str, jax.Array]:
-    """One layer's leaves by flat name, regenerated from the seed."""
+def _values(seed: int, leaves, layer: int) -> Dict[str, jax.Array]:
     return {n: _leaf(jnp.asarray(leaf_key(seed, n, layer)), shape=s,
                      dtype=dt, init=init)
-            for n, s, dt, init in layer_leaves(c)}
+            for n, s, dt, init in leaves}
+
+
+def layer_weights(c: dict, seed: int, layer: int) -> Dict[str, jax.Array]:
+    """One layer's leaves by flat name, regenerated from the seed."""
+    out: Dict[str, jax.Array] = {}
+    for g in layout(c):
+        if g.layers is not None and layer in g.layers:
+            out.update(_values(seed, g.leaves, layer))
+    return out
 
 
 def top_weights(c: dict, seed: int) -> Dict[str, jax.Array]:
-    return {n: _leaf(jnp.asarray(leaf_key(seed, n, 0)), shape=s, dtype=dt,
-                     init=init)
-            for n, s, dt, init in top_leaves(c)}
+    out: Dict[str, jax.Array] = {}
+    for g in layout(c):
+        if g.layers is None:
+            out.update(_values(seed, g.leaves, 0))
+    return out
 
 
-def _nest(flat: Dict[str, jax.Array]) -> dict:
-    out: dict = {}
+def _place(tree: dict, path: str, flat: Dict[str, jax.Array]) -> None:
+    """Put ``flat`` (dotted leaf names) under ``path`` of ``tree``."""
     for name, v in flat.items():
-        node = out
-        parts = name.split(".")
+        node = tree
+        parts = [p for p in path.split("/") if p] + name.split(".")
         for p in parts[:-1]:
             node = node.setdefault(p, {})
         node[parts[-1]] = v
-    return out
 
 
 def make_params(c: dict, seed: int, device=None) -> dict:
     """The program's parameter tree for this configuration, made on the
-    device in one jitted call: layers stacked on a leading axis as the
-    program scans them."""
-    L = c["num_hidden_layers"]
-    lleaves, tleaves = layer_leaves(c), top_leaves(c)
-    keys = {n: np.stack([leaf_key(seed, n, i) for i in range(L)])
-            for n, *_ in lleaves}
-    keys.update({n: leaf_key(seed, n, 0) for n, *_ in tleaves})
+    device in one jitted call: each group's layers stacked on a leading
+    axis where the program scans them."""
+    groups = layout(c)
+    keys = []
+    for g in groups:
+        if g.layers is None or not g.stacked:
+            at = 0 if g.layers is None else g.layers[0]
+            keys.append({n: leaf_key(seed, n, at) for n, *_ in g.leaves})
+        else:
+            keys.append({n: np.stack([leaf_key(seed, n, i) for i in g.layers])
+                         for n, *_ in g.leaves})
 
     def build(keys):
-        stacked = {n: jax.vmap(lambda k, s=s, dt=dt, init=init:
-                               leaf_values(k, s, dt, init))(keys[n])
-                   for n, s, dt, init in lleaves}
-        tree = _nest({n: leaf_values(keys[n], s, dt, init)
-                      for n, s, dt, init in tleaves})
-        tree["stacked"] = {"0": _nest(stacked)}
+        tree: dict = {}
+        for g, k in zip(groups, keys):
+            if g.layers is None or not g.stacked:
+                flat = {n: leaf_values(k[n], s, dt, init)
+                        for n, s, dt, init in g.leaves}
+            else:
+                flat = {n: jax.vmap(lambda x, s=s, dt=dt, init=init:
+                                    leaf_values(x, s, dt, init))(k[n])
+                        for n, s, dt, init in g.leaves}
+            _place(tree, g.path, flat)
         return tree
 
     with jax.default_device(device):

@@ -22,7 +22,10 @@ import jax
 @dataclass
 class Call:
     origin: str          # worker call: "execute" | "decode_once" | "fused_step"
-    kernel: str          # attention kernel the step runs: "flash" | "ragged"
+    #: the step's kind, named by the attention kernel it runs: "flash" (a
+    #: dense prefill or fused step), "decode" (a dense decode step, one
+    #: query per row), "ragged" (a packed step)
+    kernel: str
     t0: float            # loop seconds
     t1: float
     segments: List[Tuple[int, int]]   # (new tokens, cached context) per row
@@ -106,7 +109,7 @@ class Recorder:
                 packed = (w.packed
                           and eng.packed_bucket(total) < w.max_slots)
                 self.calls.append(Call("decode_once",
-                                       "ragged" if packed else "flash",
+                                       "ragged" if packed else "decode",
                                        t0, t1, rows))
             return out
 

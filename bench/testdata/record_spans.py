@@ -38,7 +38,7 @@ if __name__ == "__main__":
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "num_hidden_layers": 2, "vocab_size": 1024, "rms_norm_eps": 1e-5,
          "rope_theta": 1e4, "tie_word_embeddings": False,
-         "attention_bias": True}
+         "attention_bias": True, "reference": "decoder"}
     cfg = model_config(c)
     params = make_params(c, 1)
     be = LiveBackend(PerfModel(cfg))

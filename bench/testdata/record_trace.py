@@ -4,9 +4,11 @@
 
 Run on a TPU host from the root of a checkout.  It serves a tiny decoder
 through the program's ``Engine`` (a dense prefill, a dense decode step and
-a packed step, so both attention kernels and both step programs appear)
-under the benchmark's own host spans, and writes ``engine_steps.xplane.pb``
-beside this file.
+a packed step, so the three attention kernels and both step programs
+appear) under the benchmark's own host spans, and writes
+``engine_steps.xplane.pb`` beside this file.  The traced decode step runs
+four rows holding 41, 2, 1 and 1 cached tokens (the warm-up's decode step
+and packed step wrote them).
 """
 import glob
 import os
@@ -31,7 +33,7 @@ if __name__ == "__main__":
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "num_hidden_layers": 2, "vocab_size": 1024, "rms_norm_eps": 1e-5,
          "rope_theta": 1e4, "tie_word_embeddings": False,
-         "attention_bias": True}
+         "attention_bias": True, "reference": "decoder"}
     eng = Engine(model_config(c), max_len=256, params=make_params(c, 1))
     cache = eng.new_cache(4)
     ids = np.arange(64, dtype=np.int32) % 1024

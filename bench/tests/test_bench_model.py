@@ -12,19 +12,18 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-from bench import model  # noqa: E402
+from bench import harness, model  # noqa: E402
 
-FIX = Path(__file__).resolve().parent / "fixtures" / "configs"
+FIX = Path(__file__).resolve().parent / "fixtures"
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+TINY = ["tiny-decoder", "tiny-moe", "tiny-windowed"]
 
 
 def _cfg(name):
-    c = json.loads((FIX / f"{name}.json").read_text())
-    c["name"] = name
-    return c
+    return harness.load_config(name, (FIX, harness.BENCH_DIR))
 
 
-@pytest.mark.parametrize("name", ["tiny-decoder", "tiny-moe"])
+@pytest.mark.parametrize("name", TINY)
 def test_tree_matches_the_program_layout(name):
     from repro.models.transformer import init_params
     c = _cfg(name)
@@ -36,24 +35,33 @@ def test_tree_matches_the_program_layout(name):
         assert a.shape == b.shape and a.dtype == b.dtype
 
 
-@pytest.mark.parametrize("name", ["tiny-decoder", "tiny-moe"])
+def _at(tree, path, name):
+    node = tree
+    for part in [p for p in path.split("/") if p] + name.split("."):
+        node = node[part]
+    return node
+
+
+@pytest.mark.parametrize("name", TINY)
 def test_layers_regenerate_bit_for_bit(name):
+    """Every layer's leaves, wherever its group puts them (stacked at the
+    layer's place in the group, or unstacked), and the top leaves."""
     c = _cfg(name)
     seed = 2 ** 40 + 17
     tree = model.make_params(c, seed)
-    stacked = tree["stacked"]["0"]
-    for layer in range(c["num_hidden_layers"]):
+    where = {}
+    for g in model.layout(c):
+        for i, layer in enumerate(g.layers or ()):
+            where[layer] = (g.path, i if g.stacked else None)
+    assert sorted(where) == list(range(c["num_hidden_layers"]))
+    for layer, (path, i) in where.items():
         for n, v in model.layer_weights(c, seed, layer).items():
-            node = stacked
-            for part in n.split("."):
-                node = node[part]
-            np.testing.assert_array_equal(np.asarray(node[layer]),
-                                          np.asarray(v))
+            got = _at(tree, path, n)
+            np.testing.assert_array_equal(
+                np.asarray(got if i is None else got[i]), np.asarray(v))
     for n, v in model.top_weights(c, seed).items():
-        node = tree
-        for part in n.split("."):
-            node = node[part]
-        np.testing.assert_array_equal(np.asarray(node), np.asarray(v))
+        np.testing.assert_array_equal(np.asarray(_at(tree, "", n)),
+                                      np.asarray(v))
 
 
 def test_seeds_give_different_weights():

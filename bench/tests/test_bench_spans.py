@@ -11,7 +11,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import layers  # noqa: E402
+from bench import layers, trace  # noqa: E402
 
 RECORDED = ROOT / "bench" / "testdata" / "engine_steps_spans.xplane.pb"
 
@@ -69,3 +69,15 @@ def test_decode_wait_meets_the_step_program():
     step = [h for h in host if h[0] == "repro.decode.step"][0]
     assert any(s[0].startswith("jit__step") and s[1] < step[2] + SKEW_NS
                and step[1] - SKEW_NS < s[2] for s in steps)
+
+
+def test_load_keeps_program_spans():
+    """``trace.load`` keeps every program span, on the trace's clock in
+    seconds, and only those (the benchmark's own stay in ``host``)."""
+    from repro.runtime.tracing import SPANS
+    host, _ = _spans()
+    tr = trace.load(str(RECORDED))
+    assert sorted(tr.spans) == sorted((n, s * 1e-9, e * 1e-9)
+                                      for n, s, e, _ in host if n in SPANS)
+    assert len(tr.spans) == len(host)
+    assert all(not n.startswith("repro.") for n, _, _ in tr.host)

@@ -45,6 +45,18 @@ def test_idle_gaps_are_named_by_the_host_span():
                     ["host", pytest.approx(1.5)]]
 
 
+def test_idle_gaps_prefer_the_program_span():
+    """A program span covering a gap's middle names it (the one that
+    started last), before the benchmark's span around the call."""
+    tr = _trace()
+    tr.spans = [("repro.fused", 4.0, 8.0), ("repro.decode.sample", 5.0, 7.0),
+                ("repro.decode.wait", 6.0, 6.5)]
+    # gap [4.5, 9] has its middle at 6.75; gap [2.5, 4] at 3.25
+    assert trace.idle_gaps(tr, 0.0, 10.0, n=2) == [
+        ["repro.decode.sample", pytest.approx(4.5)],
+        ["host", pytest.approx(1.5)]]
+
+
 RECORDED = Path(__file__).resolve().parents[1] / "testdata" / "engine_steps.xplane.pb"
 
 
@@ -58,14 +70,58 @@ def test_recorded_v5e_trace():
     assert [h[0] for h in tr.host] == ["bench:window", "bench:prefill",
                                        "bench:decode", "bench:fused"]
     flash = trace.matching(trace.all_ops(tr), layers.KERNEL_OP["flash"], lo, hi)
+    decode = trace.matching(trace.all_ops(tr), r"^%decode_attention\b", lo, hi)
     ragged = trace.matching(trace.all_ops(tr), layers.KERNEL_OP["ragged"], lo, hi)
-    # two layers: the prefill and the decode step each run flash per layer,
-    # the packed step runs the ragged kernel per layer
-    assert len(flash) == 4 and len(ragged) == 2
-    assert trace.time_s(flash) > 0 and trace.time_s(ragged) > 0
+    # two layers: the prefill runs flash per layer, the decode step the
+    # decode kernel, the packed step the ragged kernel
+    assert len(flash) == 2 and len(decode) == 2 and len(ragged) == 2
+    assert all(trace.time_s(k) > 0 for k in (flash, decode, ragged))
     steps = trace.matching(trace.all_modules(tr), layers.STEP_PROGRAM, lo, hi)
     assert sorted(s[0].split("(")[0] for s in steps) == [
         "jit__pstep", "jit__step", "jit__step"]
     labels = [k for k, _ in trace.top_ops(
         [s for s in trace.all_ops(tr) if lo <= s[1] < hi])]
-    assert "flash_attention" in labels and "ragged_attention" in labels
+    assert {"flash_attention", "decode_attention",
+            "ragged_attention"} <= set(labels)
+
+
+def test_kernel_rooflines_on_the_recorded_trace():
+    """Each roofline reader takes its own kernel's calls by step kind: the
+    decode kernel's reads the dense decode step (four rows holding 41, 2, 1
+    and 1 cached tokens) against ``%decode_attention``, the prefill
+    kernel's the 64-token prefill against ``%flash_attention``; both
+    layers of the tiny decoder run each kernel once per call."""
+    from types import SimpleNamespace
+
+    from bench import harness, layers, work
+    from bench.probes import Call
+    c = {"name": "tiny", "hidden_size": 256, "intermediate_size": 512,
+         "num_attention_heads": 4, "num_key_value_heads": 2,
+         "num_hidden_layers": 2, "vocab_size": 1024, "rms_norm_eps": 1e-5,
+         "rope_theta": 1e4, "tie_word_embeddings": False,
+         "attention_bias": True, "reference": "decoder"}
+    decode = [(1, 41), (1, 2), (1, 1), (1, 1)]
+    calls = [Call("execute", "flash", 0.1, 0.2, [(64, 0)]),
+             Call("decode_once", "decode", 0.3, 0.4, decode),
+             Call("fused_step", "ragged", 0.5, 0.6, [(40, 42), (1, 3)])]
+    run = harness.Run(
+        config=c, mix={}, cell={}, seconds=1.0, w0=0.0, w1=1.0,
+        drain_end=1.0, device_kind="TPU v5 lite", setup_s=0.0, rounds=[],
+        all_rounds=[], recorder=SimpleNamespace(calls=calls), counters={},
+        compile_times=[], trace=trace.load(str(RECORDED)),
+        trace_window=(0.0, 1.0))
+    lo, hi = run.trace.window
+
+    def share(op, segs):
+        dev = trace.time_s(trace.matching(trace.all_ops(run.trace), op, lo, hi))
+        need = 2 * work.roofline_s(*work.attention_work(c, segs), run.device_kind)
+        return 100.0 * need / dev
+
+    readers = harness.metric_readers(
+        ["decode_attn_roofline", "flash_prefill_roofline"], (harness.BENCH_DIR,))
+    got = readers["decode_attn_roofline"].read(run)
+    assert got == pytest.approx(share(r"^%decode_attention\b", decode))
+    assert 0.0 < got <= 100.0
+    got = readers["flash_prefill_roofline"].read(run)
+    assert got == pytest.approx(share(layers.KERNEL_OP["flash"], [(64, 0)]))
+    assert 0.0 < got <= 100.0

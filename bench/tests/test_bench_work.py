@@ -9,7 +9,8 @@ import pytest  # noqa: E402
 from bench import work  # noqa: E402
 
 C = {"hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
-     "intermediate_size": 16, "vocab_size": 10, "num_hidden_layers": 3}
+     "intermediate_size": 16, "vocab_size": 10, "num_hidden_layers": 3,
+     "reference": "decoder"}
 
 
 def test_attention_work_by_hand():

@@ -4,7 +4,9 @@ A trace (``*.xplane.pb``) holds one plane per device, with a line of
 operations (``XLA Ops``) and a line of whole programs (``XLA Modules``),
 and a host plane whose thread lines carry the benchmark's own spans
 (``bench:window`` around the traced window, ``bench:<call>`` around each
-worker call).  Times are in seconds on the trace's one clock.
+worker call) and the program's (the names in
+``repro.runtime.tracing.SPANS``).  Times are in seconds on the trace's one
+clock.
 """
 from __future__ import annotations
 
@@ -26,7 +28,8 @@ class Trace:
     #: device plane name -> operations / programs on it
     ops: Dict[str, List[Span]] = field(default_factory=dict)
     modules: Dict[str, List[Span]] = field(default_factory=dict)
-    host: List[Span] = field(default_factory=list)
+    host: List[Span] = field(default_factory=list)      # bench:* spans
+    spans: List[Span] = field(default_factory=list)     # the program's
 
     @property
     def window(self) -> Optional[Tuple[float, float]]:
@@ -42,6 +45,12 @@ def find_xplane(directory: str) -> Optional[str]:
 
 def load(path: str) -> Trace:
     from jax.profiler import ProfileData
+
+    # ``repro.runtime`` imports cleanly only after ``repro.core`` (a
+    # circular import between the two packages otherwise)
+    import repro.core  # noqa: F401
+    from repro.runtime.tracing import SPANS
+    program = set(SPANS)
     data = ProfileData.from_file(path)
     tr = Trace()
     for plane in data.planes:
@@ -55,10 +64,12 @@ def load(path: str) -> Trace:
                     dest.setdefault(plane.name, []).extend(spans)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                tr.host.extend((e.name, e.start_ns * 1e-9,
-                                (e.start_ns + e.duration_ns) * 1e-9)
-                               for e in line.events
-                               if e.name.startswith("bench:"))
+                for e in line.events:
+                    dest = (tr.host if e.name.startswith("bench:") else
+                            tr.spans if e.name in program else None)
+                    if dest is not None:
+                        dest.append((e.name, e.start_ns * 1e-9,
+                                     (e.start_ns + e.duration_ns) * 1e-9))
     return tr
 
 
@@ -122,8 +133,9 @@ def top_ops(spans: Sequence[Span], n: int = 10) -> List[List]:
 
 
 def idle_gaps(tr: Trace, lo: float, hi: float, n: int = 10) -> List[List]:
-    """The longest gaps with no device operation, each named by the host
-    span that covered the middle of the gap (``host`` if none did)."""
+    """The longest gaps with no device operation, each named by what the
+    host was doing at the gap's middle: the program span covering it that
+    started last, else the benchmark's span that did, else ``host``."""
     ops = sorted((s, e) for _, s, e in all_ops(tr) if e > lo and s < hi)
     gaps, cur = [], lo
     for s, e in ops:
@@ -136,6 +148,10 @@ def idle_gaps(tr: Trace, lo: float, hi: float, n: int = 10) -> List[List]:
     out = []
     for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:n]:
         mid = (s + e) / 2
-        names = [h[0] for h in host if h[1] <= mid < h[2]]
-        out.append([names[-1] if names else "host", e - s])
+        name = "host"
+        for spans in (host, tr.spans):
+            cover = [h for h in spans if h[1] <= mid < h[2]]
+            if cover:
+                name = max(cover, key=lambda h: h[1])[0]
+        out.append([name, e - s])
     return out
